@@ -298,8 +298,9 @@ def sparse_vs_dense_rows(quiet: bool = False):
     neighbor matrix stores ``n * d_max`` entries in ELL form (int32
     index + fp32 weight) against the dense ``n^2`` fp32 layout, so the
     operand footprint scales O(n) instead of O(n^2) -- the ratio below
-    is n/(2 d_max) and grows without bound.  Wall times are interpret-
-    mode CPU and NOT baseline-gated (the new ``bytes_A_*`` fields are
+    is n/(2 d_max) and grows without bound.  Wall times are CPU times
+    (the Pallas kernels in interpret mode, the ELL mix an XLA gather)
+    and NOT baseline-gated (the new ``bytes_A_*`` fields are
     informational, outside ``_BYTE_FIELDS``, so the committed gate is
     untouched).
     """

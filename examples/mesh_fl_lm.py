@@ -19,7 +19,6 @@ os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
 
 import argparse                                                 # noqa: E402
-from contextlib import nullcontext                              # noqa: E402
 from dataclasses import replace                                 # noqa: E402
 
 import jax                                                      # noqa: E402
@@ -86,10 +85,7 @@ def main():
 
     server = FederatedServer(network, None, params, sampler, scfg,
                              algorithm="semidec", execution=execution)
-    # jax >= 0.6 wants an ambient mesh for GSPMD; 0.4.x resolves the
-    # explicit NamedShardings without one
-    with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh")
-          else nullcontext()):
+    with jax.set_mesh(mesh):
         history = server.run(eval_fn=eval_fn, plan=plan)
 
     for rec in history.records:

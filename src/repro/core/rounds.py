@@ -42,7 +42,7 @@ backends (``make_round_fn(..., mixing_backend=...)``):
               (``repro.core.sparse.SparseA.ell()``) instead of an (n, n)
               matrix, and eq. 3 runs as d_max row gathers while the eq.-4
               combine row is a segment-sum over the same entries
-              (``kernels.mixing.sparse``).  O(n d_max p) work and O(n
+              (``kernels.mixing.ops``).  O(n d_max p) work and O(n
               d_max) topology storage -- the only backends that scale n
               past the dense O(n^2) wall.  allclose (not bitwise) to
               'einsum': fp32 accumulation both sides, reduction order

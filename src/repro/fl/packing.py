@@ -67,8 +67,9 @@ class QuantSpec:
     the ``storage`` container:
 
       'int8'  -- one int8 per value, qmax 127 (~2x vs bf16, ~4x vs fp32)
-      'int4'  -- two values packed per int8 byte (low nibble first),
-                 qmax 7 -- the aggressive knob (~4x vs bf16)
+      'int4'  -- two values packed per int8 byte (a block's first half
+                 in the low nibbles, its second half in the high
+                 nibbles), qmax 7 -- the aggressive knob (~4x vs bf16)
       'fp8'   -- float8_e4m3fn per value, qmax 448 (scale maps the block
                  absmax onto the fp8 dynamic range; rounding is the
                  cast's round-to-nearest, so ``rounding='stochastic'``
@@ -394,20 +395,29 @@ def apply_aggregate_row(global_params: PyTree,
 # ---------------------------------------------------------------------------
 
 
-def _pack_nibbles(v: jnp.ndarray) -> jnp.ndarray:
-    """(n, p) int8 values in [-8, 7] -> (n, p//2) packed bytes: column
-    2j in the low nibble, 2j+1 in the high nibble of byte j."""
+def _pack_nibbles(v: jnp.ndarray, block: int) -> jnp.ndarray:
+    """(n, p) int8 values in [-8, 7] -> (n, p//2) packed bytes, block by
+    block: value j of a ``block``-value block sits in the low nibble of
+    the block's byte j, value ``j + block // 2`` in its high nibble.  The
+    kernels then unpack a tile by concatenating lane-aligned halves
+    (``repro.kernels.mixing.fused.dequant_tile``)."""
     n, p = v.shape
-    pairs = v.reshape(n, p // 2, 2)
-    return (pairs[..., 0] & jnp.int8(0x0F)) | (pairs[..., 1] << 4)
+    halves = v.reshape(n, p // block, 2, block // 2)
+    packed = (halves[:, :, 0] & jnp.int8(0x0F)) | (halves[:, :, 1] << 4)
+    return packed.reshape(n, p // 2)
 
 
-def _unpack_nibbles(q: jnp.ndarray) -> jnp.ndarray:
+def _unpack_nibbles(q: jnp.ndarray, block: int) -> jnp.ndarray:
     """Inverse of ``_pack_nibbles``: sign-extend both nibbles of every
-    byte and re-interleave -- (n, p//2) int8 -> (n, p) int8."""
+    byte and put each block's halves back -- (n, p//2) int8 -> (n, p)
+    int8."""
+    n, pq = q.shape
+    h = block // 2
     lo = (q << 4) >> 4            # shift out the high nibble, extend back
     hi = q >> 4                   # arithmetic shift sign-extends
-    return jnp.stack([lo, hi], axis=-1).reshape(q.shape[0], -1)
+    halves = jnp.stack([lo.reshape(n, pq // h, h),
+                        hi.reshape(n, pq // h, h)], axis=2)
+    return halves.reshape(n, 2 * pq)
 
 
 def quantize_group(buf: jnp.ndarray, quant: QuantSpec,
@@ -441,7 +451,8 @@ def quantize_group(buf: jnp.ndarray, quant: QuantSpec,
             v = jnp.round(y)
         v = jnp.clip(v, -quant.qmax, quant.qmax)
         v = v.astype(jnp.int8).reshape(n, p)
-        stored = _pack_nibbles(v) if quant.storage == "int4" else v
+        stored = (_pack_nibbles(v, quant.block) if quant.storage == "int4"
+                  else v)
     return stored, scales
 
 
@@ -455,7 +466,7 @@ def dequantize_group(stored: jnp.ndarray, scales: jnp.ndarray,
     n = stored.shape[0]
     nb = scales.shape[1]
     if quant.storage == "int4":
-        v = _unpack_nibbles(stored).astype(jnp.float32)
+        v = _unpack_nibbles(stored, quant.block).astype(jnp.float32)
     else:
         v = stored.astype(jnp.float32)
     x = v.reshape(n, nb, quant.block) * scales[:, :, None]

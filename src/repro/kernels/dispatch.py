@@ -9,8 +9,9 @@ variable or per-call by passing ``interpret=`` explicitly.
 
 ``REPRO_PALLAS_INTERPRET`` accepts ``1/true/interpret`` (force the
 interpreter, e.g. to debug a miscompile on TPU) and ``0/false/compiled``
-(force compiled lowering, e.g. under a TPU simulator the sniff cannot
-see).  Any other value raises at first kernel dispatch.
+(force compiled lowering).  Any other value raises at first kernel
+dispatch.  ``chip_smoke.py`` refuses to run when the interpreter is
+selected on the chip.
 """
 
 from __future__ import annotations

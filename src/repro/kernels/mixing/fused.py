@@ -50,6 +50,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .mixing import f32_matmul
+
 __all__ = ["mix_aggregate_pallas", "aggregate_pallas", "dequant_tile",
            "mix_aggregate_dequant_pallas", "aggregate_dequant_pallas"]
 
@@ -58,18 +60,14 @@ def _fused_kernel(a_ref, w_ref, x_ref, mixed_ref, agg_ref):
     a = a_ref[...].astype(jnp.float32)          # (n_pad, n_pad), resident
     w = w_ref[...].astype(jnp.float32)          # (s, n_pad), resident
     x = x_ref[...].astype(jnp.float32)          # (n_pad, pc) -- read ONCE
-    dims = (((1,), (0,)), ((), ()))
-    mixed_ref[...] = jax.lax.dot_general(
-        a, x, dims, preferred_element_type=jnp.float32).astype(mixed_ref.dtype)
-    agg_ref[...] = jax.lax.dot_general(
-        w, x, dims, preferred_element_type=jnp.float32)
+    mixed_ref[...] = f32_matmul(a, x).astype(mixed_ref.dtype)
+    agg_ref[...] = f32_matmul(w, x)
 
 
 def _agg_kernel(w_ref, x_ref, agg_ref):
     w = w_ref[...].astype(jnp.float32)          # (s, n_pad), resident
     x = x_ref[...].astype(jnp.float32)          # (n_pad, pc) -- read ONCE
-    agg_ref[...] = jax.lax.dot_general(
-        w, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    agg_ref[...] = f32_matmul(w, x)
 
 
 def mix_aggregate_pallas(A: jnp.ndarray, w: jnp.ndarray, X: jnp.ndarray, *,
@@ -147,16 +145,24 @@ def dequant_tile(x: jnp.ndarray, scales: jnp.ndarray, *, storage: str,
     """In-register dequant of one payload tile.
 
     ``x`` is the stored tile -- (n, pc) for int8/fp8, (n, pc // 2)
-    nibble-packed int8 for 'int4' (low nibble = even column) --
-    ``scales`` the matching (n, pc // block) fp32 scale tile.  Returns
-    the (n, pc) fp32 values ``stored * scale``, the same arithmetic as
+    nibble-packed int8 for 'int4' (each block's first half in the low
+    nibbles, its second half in the high nibbles) -- ``scales`` the
+    matching (n, pc // block) fp32 scale tile.  Returns the (n, pc) fp32
+    values ``stored * scale``, the same arithmetic as
     ``repro.fl.packing.dequantize_group`` (host round-trips match the
-    kernel path bitwise)."""
+    kernel path bitwise).  The int4 halves are put back with static
+    lane-aligned slices (``block // 2`` is a multiple of 128), the
+    unpack Mosaic lowers."""
     n = x.shape[0]
     if storage == "int4":
-        lo = (x << 4) >> 4        # sign-extend both nibbles of each byte
+        x = x.astype(jnp.int32)   # Mosaic has no int8 shifts
+        lo = (x << 28) >> 28      # sign-extend both nibbles of each byte
         hi = x >> 4
-        v = jnp.stack([lo, hi], axis=-1).reshape(n, -1).astype(jnp.float32)
+        h = block // 2
+        v = jnp.concatenate(
+            [half[:, b * h:(b + 1) * h]
+             for b in range(x.shape[1] // h) for half in (lo, hi)],
+            axis=1).astype(jnp.float32)
     else:
         v = x.astype(jnp.float32)
     nb = scales.shape[1]
@@ -169,31 +175,34 @@ def _fused_dequant_kernel(a_ref, w_ref, x_ref, s_ref, mixed_ref, agg_ref,
     a = a_ref[...].astype(jnp.float32)          # (n_pad, n_pad), resident
     w = w_ref[...].astype(jnp.float32)          # (s, n_pad), resident
     x = dequant_tile(x_ref[...], s_ref[...], storage=storage, block=block)
-    dims = (((1,), (0,)), ((), ()))
-    mixed_ref[...] = jax.lax.dot_general(
-        a, x, dims, preferred_element_type=jnp.float32)
-    agg_ref[...] = jax.lax.dot_general(
-        w, x, dims, preferred_element_type=jnp.float32)
+    mixed_ref[...] = f32_matmul(a, x)
+    agg_ref[...] = f32_matmul(w, x)
 
 
 def _agg_dequant_kernel(w_ref, x_ref, s_ref, agg_ref, *, storage, block):
     w = w_ref[...].astype(jnp.float32)          # (s, n_pad), resident
     x = dequant_tile(x_ref[...], s_ref[...], storage=storage, block=block)
-    agg_ref[...] = jax.lax.dot_general(
-        w, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    agg_ref[...] = f32_matmul(w, x)
 
 
 def _quant_grid(Xq, S, storage, block, chunk):
     """Shared shape plumbing for the dequant kernels: payload width in
-    *value* columns, container columns per chunk, scale blocks per
-    chunk."""
+    *value* columns, container columns per chunk, and the scales laid out
+    one (n, chunk // block) tile per chunk -- ``(p // chunk, n, chunk //
+    block)``, so the scale BlockSpec's trailing dims equal the array's
+    (Mosaic refuses a (n, chunk // block) block of the (n, p // block)
+    buffer: its lane dim is neither 128-divisible nor the whole axis)."""
     assert chunk % block == 0, (chunk, block)
-    p = S.shape[1] * block                       # value columns
+    n, nb = S.shape
+    p = nb * block                               # value columns
     qcols = chunk // 2 if storage == "int4" else chunk
     assert Xq.shape[1] * (2 if storage == "int4" else 1) == p, \
         (Xq.shape, S.shape, block)
     assert p % chunk == 0, (p, chunk)
-    return p, qcols, chunk // block
+    sb = chunk // block
+    S_tiles = S.reshape(n, p // chunk, sb).transpose(1, 0, 2)
+    return p, qcols, S_tiles, pl.BlockSpec((None, n, sb),
+                                           lambda i: (i, 0, 0))
 
 
 def mix_aggregate_dequant_pallas(A: jnp.ndarray, w: jnp.ndarray,
@@ -208,7 +217,7 @@ def mix_aggregate_dequant_pallas(A: jnp.ndarray, w: jnp.ndarray,
     (n_pad, p_pad) and (s, p_pad)."""
     n = Xq.shape[0]
     s = w.shape[0]
-    p, qcols, sblocks = _quant_grid(Xq, S, storage, block, chunk)
+    p, qcols, S_tiles, s_spec = _quant_grid(Xq, S, storage, block, chunk)
     assert A.shape == (n, n) and w.shape == (s, n), (A.shape, w.shape)
     grid = (p // chunk,)
     return pl.pallas_call(
@@ -219,7 +228,7 @@ def mix_aggregate_dequant_pallas(A: jnp.ndarray, w: jnp.ndarray,
             pl.BlockSpec((n, n), lambda i: (0, 0)),        # A resident
             pl.BlockSpec((s, n), lambda i: (0, 0)),        # w resident
             pl.BlockSpec((n, qcols), lambda i: (0, i)),    # stored payload
-            pl.BlockSpec((n, sblocks), lambda i: (0, i)),  # scale side buf
+            s_spec,                                        # scale side buf
         ],
         out_specs=[
             pl.BlockSpec((n, chunk), lambda i: (0, i)),
@@ -230,7 +239,7 @@ def mix_aggregate_dequant_pallas(A: jnp.ndarray, w: jnp.ndarray,
             jax.ShapeDtypeStruct((s, p), jnp.float32),
         ],
         interpret=interpret,
-    )(A, w, Xq, S)
+    )(A, w, Xq, S_tiles)
 
 
 def aggregate_dequant_pallas(w: jnp.ndarray, Xq: jnp.ndarray,
@@ -242,7 +251,7 @@ def aggregate_dequant_pallas(w: jnp.ndarray, Xq: jnp.ndarray,
     dequantized payload ever exist in HBM.  Returns (s, p_pad) fp32."""
     n = Xq.shape[0]
     s = w.shape[0]
-    p, qcols, sblocks = _quant_grid(Xq, S, storage, block, chunk)
+    p, qcols, S_tiles, s_spec = _quant_grid(Xq, S, storage, block, chunk)
     assert w.shape == (s, n), (w.shape, Xq.shape)
     grid = (p // chunk,)
     return pl.pallas_call(
@@ -252,9 +261,9 @@ def aggregate_dequant_pallas(w: jnp.ndarray, Xq: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((s, n), lambda i: (0, 0)),        # w resident
             pl.BlockSpec((n, qcols), lambda i: (0, i)),    # stored payload
-            pl.BlockSpec((n, sblocks), lambda i: (0, i)),  # scale side buf
+            s_spec,                                        # scale side buf
         ],
         out_specs=pl.BlockSpec((s, chunk), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((s, p), jnp.float32),
         interpret=interpret,
-    )(w, Xq, S)
+    )(w, Xq, S_tiles)

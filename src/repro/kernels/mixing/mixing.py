@@ -26,21 +26,27 @@ mix-only kernel remains for the 'pallas' leaf-wise backend and ablations.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["mix_pallas"]
+__all__ = ["mix_pallas", "f32_matmul"]
+
+
+def f32_matmul(a: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """``a @ x`` of fp32 operands with fp32 products and accumulation.
+    Mosaic's default precision rounds fp32 operands to bf16 (on a v5e
+    that put ~2e-3 relative error into the CNN aggregate); HIGHEST keeps
+    the kernels at the fp32 reference's accuracy."""
+    return jax.lax.dot_general(
+        a, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
 
 
 def _mix_kernel(a_ref, x_ref, o_ref):
     a = a_ref[...].astype(jnp.float32)          # (n_pad, n_pad)
     x = x_ref[...].astype(jnp.float32)          # (n_pad, pc)
-    o_ref[...] = jax.lax.dot_general(
-        a, x, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    o_ref[...] = f32_matmul(a, x).astype(o_ref.dtype)
 
 
 def mix_pallas(A: jnp.ndarray, X: jnp.ndarray, *, chunk: int = 2048,

@@ -41,8 +41,6 @@ from .fused import (aggregate_dequant_pallas, aggregate_pallas,
                     mix_aggregate_dequant_pallas, mix_aggregate_pallas)
 from .mixing import mix_pallas
 from .ref import mix_ref
-from .sparse import (sparse_mix_aggregate_dequant_pallas,
-                     sparse_mix_aggregate_pallas, sparse_mix_pallas)
 
 PyTree = Any
 
@@ -399,22 +397,28 @@ def aggregate_grouped_q(A: jnp.ndarray, tau: jnp.ndarray, m: jnp.ndarray,
 
 # --------------------------------------------------------------------------
 # Sparse (ELL) entry points -- A as padded neighbor lists
-# (``repro.core.sparse.SparseA.ell()``), never an (n, n) array.
+# (``repro.core.sparse.SparseA.ell()``), never an (n, n) array: ``idx`` /
+# ``w`` (n, d_max) hold each destination row's in-neighbor ids and
+# ``A[i, j]`` weights, padding slots carry index 0 / weight 0.0 (a
+# gather scaled by zero, a no-op needing no mask).  Eq. 3 is d_max row
+# gathers in XLA (``_ell_mix``; Mosaic cannot lower a dynamic row gather
+# inside a kernel), and the eq.-4 aggregate is the combine row built by
+# an O(nnz) segment-sum (``combine_weights_ell``) applied by the dense
+# aggregate kernels.  Work is O(n d_max p); nothing is (n, n).
 # --------------------------------------------------------------------------
 
 
-def _pad_sparse_inputs(idx, w, X, chunk):
-    """Pad (idx, w, X) to TPU tile alignment; padded rows carry index 0 /
-    weight 0.0 (the kernels' no-op slot convention).  Returns
-    ``(idx_p, w_p, X_p, n, p)``."""
-    n, p = X.shape
-    d = idx.shape[1]
-    n_pad = _pad_to(n, _SUBLANE)
-    p_pad = _pad_to(p, chunk)
-    idx_p = jnp.zeros((n_pad, d), jnp.int32).at[:n].set(idx)
-    w_p = jnp.zeros((n_pad, d), jnp.float32).at[:n].set(w)
-    X_p = jnp.zeros((n_pad, p_pad), X.dtype).at[:n, :p].set(X)
-    return idx_p, w_p, X_p, n, p
+def _ell_mix(idx, w, X):
+    """``mixed[i] = sum_k w[i, k] * X[idx[i, k]]`` -- an fp32 (n, p)
+    accumulator, neighbor slots added in order.  allclose (not bitwise)
+    to the dense ``A @ X``: the reduction order differs."""
+    w = w.astype(jnp.float32)
+
+    def add(k, acc):
+        return acc + w[:, k, None] * X[idx[:, k]].astype(jnp.float32)
+
+    return jax.lax.fori_loop(0, idx.shape[1], add,
+                             jnp.zeros(X.shape, jnp.float32))
 
 
 def _sparse_weight_row(idx, w_ell, tau, m, n_pad, active=None,
@@ -426,18 +430,13 @@ def _sparse_weight_row(idx, w_ell, tau, m, n_pad, active=None,
     return jnp.zeros((_SUBLANE, n_pad), jnp.float32).at[0, :n].set(wrow)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def sparse_mix(idx: jnp.ndarray, w: jnp.ndarray, X: jnp.ndarray, *,
-               chunk: int = 2048,
-               interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Sparse ``Delta = A @ X`` for arbitrary (n, p): ELL gather kernel,
+@jax.jit
+def sparse_mix(idx: jnp.ndarray, w: jnp.ndarray,
+               X: jnp.ndarray) -> jnp.ndarray:
+    """Sparse ``Delta = A @ X`` for arbitrary (n, p), in X.dtype:
     O(n d_max p) work.  allclose to the dense ``mix`` (fp32 accumulation
     both sides; reduction order differs)."""
-    interpret = resolve_interpret(interpret)
-    idx_p, w_p, X_p, n, p = _pad_sparse_inputs(idx, w, X, chunk)
-    out = sparse_mix_pallas(idx_p, w_p, X_p, chunk=chunk,
-                            interpret=interpret)
-    return out[:n, :p]
+    return _ell_mix(idx, w, X).astype(X.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -448,18 +447,13 @@ def sparse_mix_aggregate(idx: jnp.ndarray, w: jnp.ndarray,
                          active: Optional[jnp.ndarray] = None,
                          weights: Optional[jnp.ndarray] = None
                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Sparse fused eq. 3 + eq. 4: one streaming pass over ``X`` emits
-    the mixed payload and the fp32 aggregate row, with the combine row
-    built by segment-sum (O(nnz)).  Mask/weight semantics match
-    ``mix_aggregate``."""
-    interpret = resolve_interpret(interpret)
-    idx_p, w_p, X_p, n, p = _pad_sparse_inputs(idx, w, X, chunk)
-    wrow_p = _sparse_weight_row(idx, w, tau, m, idx_p.shape[0], active,
-                                weights)
-    mixed, agg = sparse_mix_aggregate_pallas(idx_p, w_p, wrow_p, X_p,
-                                             chunk=chunk,
-                                             interpret=interpret)
-    return mixed[:n, :p], agg[0, :p]
+    """Sparse eq. 3 + eq. 4: the mixed payload (X.dtype) and the fp32
+    aggregate row, with the combine row built by segment-sum (O(nnz)).
+    Mask/weight semantics match ``mix_aggregate``."""
+    agg = sparse_aggregate(idx, w, tau, m, X, chunk=chunk,
+                           interpret=interpret, active=active,
+                           weights=weights)
+    return sparse_mix(idx, w, X), agg
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -470,12 +464,13 @@ def sparse_aggregate(idx: jnp.ndarray, w: jnp.ndarray, tau: jnp.ndarray,
                      weights: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Sparse aggregate-only fast path: the combine row is a segment-sum
     over the ELL entries, after which ``w @ X`` is an ordinary dense
-    vector-matrix kernel (``fused.aggregate_pallas``) -- no new kernel,
-    nothing (n, n)."""
+    vector-matrix kernel (``fused.aggregate_pallas``) -- nothing
+    (n, n)."""
     interpret = resolve_interpret(interpret)
-    idx_p, w_p, X_p, n, p = _pad_sparse_inputs(idx, w, X, chunk)
-    wrow_p = _sparse_weight_row(idx, w, tau, m, idx_p.shape[0], active,
-                                weights)
+    n, p = X.shape
+    n_pad = _pad_to(n, _SUBLANE)
+    X_p = jnp.zeros((n_pad, _pad_to(p, chunk)), X.dtype).at[:n, :p].set(X)
+    wrow_p = _sparse_weight_row(idx, w, tau, m, n_pad, active, weights)
     agg = aggregate_pallas(wrow_p, X_p, chunk=chunk, interpret=interpret)
     return agg[0, :p]
 
@@ -490,8 +485,8 @@ def sparse_mix_aggregate_grouped(idx: jnp.ndarray, w: jnp.ndarray,
                                  weights: Optional[jnp.ndarray] = None
                                  ) -> Tuple[Tuple[jnp.ndarray, ...],
                                             Tuple[jnp.ndarray, ...]]:
-    """``mix_aggregate_grouped`` on the ELL form: one sparse fused launch
-    per dtype group."""
+    """``mix_aggregate_grouped`` on the ELL form, one dtype group at a
+    time."""
     out = [sparse_mix_aggregate(idx, w, tau, m, b, chunk=chunk,
                                 interpret=interpret, active=active,
                                 weights=weights)
@@ -525,21 +520,17 @@ def sparse_mix_aggregate_q(idx: jnp.ndarray, w: jnp.ndarray,
                            active: Optional[jnp.ndarray] = None,
                            weights: Optional[jnp.ndarray] = None
                            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Sparse fused eq. 3 + eq. 4 over one quantized group buffer: ELL
-    gather + combine-row product over values dequantized in VMEM.
-    Mask/weight semantics match ``mix_aggregate_q``."""
-    _check_quant_chunk(quant, chunk)
-    interpret = resolve_interpret(interpret)
-    Xq_p, S_p, n, p = _pad_quant_inputs(Xq, S, quant, chunk)
-    n_pad = Xq_p.shape[0]
-    d = idx.shape[1]
-    idx_p = jnp.zeros((n_pad, d), jnp.int32).at[:n].set(idx)
-    w_p = jnp.zeros((n_pad, d), jnp.float32).at[:n].set(w)
-    wrow_p = _sparse_weight_row(idx, w, tau, m, n_pad, active, weights)
-    mixed, agg = sparse_mix_aggregate_dequant_pallas(
-        idx_p, w_p, wrow_p, Xq_p, S_p, storage=quant.storage,
-        block=quant.block, chunk=chunk, interpret=interpret)
-    return mixed[:n, :p], agg[0, :p]
+    """Sparse eq. 3 + eq. 4 over one quantized group buffer: the ELL
+    gather mixes the dequantized fp32 payload, the aggregate leg streams
+    the wire format through the dequant kernel.  Mask/weight semantics
+    match ``mix_aggregate_q``."""
+    # deferred: repro.fl imports the round layer, which imports this module
+    from repro.fl.packing import dequantize_group
+
+    agg = sparse_aggregate_q(idx, w, tau, m, Xq, S, quant=quant,
+                             chunk=chunk, interpret=interpret,
+                             active=active, weights=weights)
+    return _ell_mix(idx, w, dequantize_group(Xq, S, quant)), agg
 
 
 @functools.partial(jax.jit, static_argnames=("quant", "chunk", "interpret"))
@@ -552,7 +543,7 @@ def sparse_aggregate_q(idx: jnp.ndarray, w: jnp.ndarray, tau: jnp.ndarray,
                        ) -> jnp.ndarray:
     """Sparse aggregate-only dequant path: the combine row is the same
     O(nnz) segment-sum, after which the aggregate-dequant kernel streams
-    the compressed payload -- no new sparse kernel needed."""
+    the compressed payload -- no sparse kernel needed."""
     _check_quant_chunk(quant, chunk)
     interpret = resolve_interpret(interpret)
     Xq_p, S_p, n, p = _pad_quant_inputs(Xq, S, quant, chunk)

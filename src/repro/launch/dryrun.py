@@ -35,6 +35,7 @@ import jax
 
 from repro.configs import arch_names, get_config
 from repro.launch import mesh as mesh_lib
+from repro.launch.cache import enable_compile_cache
 from repro.launch import shapes as shapes_lib
 from repro.roofline import roofline_report
 from repro.roofline.jaxpr_cost import jaxpr_cost
@@ -232,6 +233,7 @@ def main(argv=None) -> int:
     ap.add_argument("--client-impl", default="vmap",
                     choices=("vmap", "shardmap"))
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     mesh_override = None
     if args.mesh:

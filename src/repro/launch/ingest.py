@@ -47,6 +47,7 @@ from repro.models import cnn as cnn_lib
 from repro.runtime import (CLOCK_KINDS, DROP_POLICIES, Recording,
                            RuntimeConfig)
 
+from .cache import enable_compile_cache
 from .train import build_model
 
 
@@ -303,6 +304,7 @@ def main(argv=None) -> int:
                     help="run the locked live/replay equivalences on a "
                          "synthetic problem and exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.selfcheck:
         return selfcheck(args.backend)

@@ -23,10 +23,18 @@ __all__ = ["make_production_mesh", "make_debug_mesh", "client_axes",
            "n_clients_of", "model_axis_size", "data_axis_size"]
 
 
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with every axis ``Auto``: the train steps place
+    tensors with ``with_sharding_constraint`` and ``NamedSharding``, which
+    only accept Auto axes (``make_mesh`` defaults to Explicit)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(shape: Tuple[int, ...],
@@ -35,7 +43,7 @@ def make_debug_mesh(shape: Tuple[int, ...],
     if axes is None:
         axes = ("pod", "data", "model")[-len(shape):] if len(shape) == 3 \
             else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def client_axes(mesh) -> Tuple[str, ...]:

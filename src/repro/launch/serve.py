@@ -27,6 +27,7 @@ import numpy as np                                              # noqa: E402
 from repro.configs import arch_names, get_config                # noqa: E402
 from repro.fl import make_decode_step, make_prefill_step       # noqa: E402
 from repro.launch.mesh import make_debug_mesh                   # noqa: E402
+from repro.launch.cache import enable_compile_cache             # noqa: E402
 from repro.models.model import Model                            # noqa: E402
 
 
@@ -39,6 +40,7 @@ def main(argv=None) -> int:
     ap.add_argument("--devices", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
     if n_dev >= 4:

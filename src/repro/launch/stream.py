@@ -45,6 +45,7 @@ from repro.fl import (ExecutionConfig, FaultSpec, RoundPlan, StreamConfig,
                       parse_fault_spec)
 from repro.models import cnn as cnn_lib
 
+from .cache import enable_compile_cache
 from .train import build_model
 
 
@@ -214,6 +215,7 @@ def main(argv=None) -> int:
                     help="run the locked bitwise equivalences on a "
                          "synthetic problem and exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.selfcheck:
         return selfcheck(args.backend)

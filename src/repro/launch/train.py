@@ -44,6 +44,7 @@ from repro.data import (FederatedBatcher, label_sorted_partition,
                         make_classification)
 from repro.core.rounds import MIXING_BACKENDS
 from repro.fl import ExecutionConfig, RoundPlan
+from repro.launch.cache import enable_compile_cache
 from repro.models import cnn as cnn_lib
 
 
@@ -133,6 +134,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="")
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     rng = np.random.default_rng(args.seed)
     ds_train = make_classification(n_samples=args.samples, seed=args.seed)

@@ -18,14 +18,6 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HELPERS = os.path.join(REPO, "tests", "helpers")
 
-# The subprocess helpers drive the mesh runtime through jax.set_mesh /
-# jax.shard_map; on older jax (< 0.7) those APIs don't exist, so the
-# multi-device equivalence checks cannot run at all -- skip, don't fail.
-needs_mesh_api = pytest.mark.skipif(
-    not (hasattr(jax, "set_mesh") and hasattr(jax, "shard_map")),
-    reason="mesh runtime requires jax.set_mesh/jax.shard_map")
-
-
 def _run(args, env_extra=None, timeout=560):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
@@ -40,7 +32,6 @@ def _run(args, env_extra=None, timeout=560):
 
 @pytest.mark.slow
 @pytest.mark.mesh
-@needs_mesh_api
 def test_mesh_train_step_matches_reference():
     r = _run([os.path.join(HELPERS, "dist_equivalence.py")],
              env_extra={"XLA_FLAGS":
@@ -56,7 +47,6 @@ def test_mesh_train_step_matches_reference():
 
 @pytest.mark.slow
 @pytest.mark.mesh
-@needs_mesh_api
 def test_sp_mlp_matches_plain():
     r = _run([os.path.join(HELPERS, "sp_mlp_equivalence.py")],
              env_extra={"XLA_FLAGS":
@@ -67,7 +57,6 @@ def test_sp_mlp_matches_plain():
 
 @pytest.mark.slow
 @pytest.mark.mesh
-@needs_mesh_api
 def test_expert_parallel_moe_matches_oracle():
     r = _run([os.path.join(HELPERS, "moe_ep_equivalence.py")],
              env_extra={"XLA_FLAGS":
@@ -79,7 +68,6 @@ def test_expert_parallel_moe_matches_oracle():
 
 @pytest.mark.slow
 @pytest.mark.mesh
-@needs_mesh_api
 def test_mesh_serve_steps_match_reference():
     r = _run([os.path.join(HELPERS, "serve_equivalence.py")],
              env_extra={"XLA_FLAGS":
@@ -95,7 +83,6 @@ def test_mesh_serve_steps_match_reference():
 
 @pytest.mark.slow
 @pytest.mark.mesh
-@needs_mesh_api
 def test_dryrun_driver_writes_artifact(tmp_path):
     out = str(tmp_path / "dry")
     r = _run(["-m", "repro.launch.dryrun", "--arch", "stablelm-1.6b",
@@ -271,9 +258,10 @@ def test_input_specs_are_abstract():
         axis_names = ("data", "model")
         shape = {"data": 16, "model": 16}
 
+    from repro.launch.mesh import make_debug_mesh
+
     # use the real (tiny) devices only through eval_shape: no allocation.
-    import jax.sharding as shd
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_debug_mesh((1, 1))
     cfg = shapes_lib.production_config(
         get_config("qwen3-32b"), shapes_lib.SHAPES["train_4k"])
     inp = shapes_lib.train_inputs(cfg, shapes_lib.SHAPES["train_4k"], mesh,

@@ -102,11 +102,33 @@ def test_zero_block_dequantizes_to_exact_zeros(storage):
 
 def test_nibble_pack_unpack_exact():
     rng = np.random.default_rng(1)
-    v = jnp.asarray(rng.integers(-8, 8, size=(3, 256)), jnp.int8)
-    packed = packing._pack_nibbles(v)
-    assert packed.shape == (3, 128)
-    np.testing.assert_array_equal(np.asarray(packing._unpack_nibbles(packed)),
-                                  np.asarray(v))
+    v = jnp.asarray(rng.integers(-8, 8, size=(3, 512)), jnp.int8)
+    packed = packing._pack_nibbles(v, 256)
+    assert packed.shape == (3, 256)
+    np.testing.assert_array_equal(
+        np.asarray(packing._unpack_nibbles(packed, 256)), np.asarray(v))
+    # block halves: byte j of a block holds values j (low) and j + 128
+    b = np.asarray(packed).astype(np.uint8)
+    np.testing.assert_array_equal((b[:, :128] & 0x0F).astype(np.int8),
+                                  np.asarray(v)[:, :128] & 0x0F)
+    np.testing.assert_array_equal((b[:, :128] >> 4).astype(np.int8),
+                                  np.asarray(v)[:, 128:256] & 0x0F)
+
+
+@pytest.mark.parametrize("storage", STORAGES)
+def test_kernel_dequant_tile_matches_host_bitwise(storage):
+    """The kernels' in-VMEM dequant is the host dequant, bit for bit."""
+    from repro.kernels.mixing.fused import dequant_tile
+
+    quant = _spec_for(storage)
+    rng = np.random.default_rng(4)
+    buf = jnp.asarray(rng.standard_normal((5, 4 * quant.block)),
+                      jnp.float32)
+    stored, scales = packing.quantize_group(buf, quant)
+    np.testing.assert_array_equal(
+        np.asarray(dequant_tile(stored, scales, storage=storage,
+                                block=quant.block)),
+        np.asarray(packing.dequantize_group(stored, scales, quant)))
 
 
 def test_int4_grid_values_roundtrip_exact():

@@ -256,7 +256,7 @@ def _kernel_inputs(n=13, p=37, seed=0):
 def test_sparse_mix_matches_dense():
     A, idx, w, X, *_ = _kernel_inputs()
     dense = ops.mix(A, X, chunk=128)
-    sparse = ops.sparse_mix(idx, w, X, chunk=128)
+    sparse = ops.sparse_mix(idx, w, X)
     assert np.allclose(dense, sparse, atol=1e-5)
 
 
