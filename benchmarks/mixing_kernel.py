@@ -1,9 +1,10 @@
 """Benchmark: fused one-pass mix+aggregate vs the two-pass schedule.
 
-Correctness (allclose across shapes/dtypes), wall time on this host
-(interpret mode on CPU; the kernels' BlockSpec tiling targets TPU VMEM),
-and a bytes-moved model of per-round HBM traffic.  Payload sizes bracket
-the paper's CNN (1.66M params) and per-leaf LM deltas.
+Correctness (allclose across shapes/dtypes) and a bytes-moved model of
+per-round HBM traffic.  Payload sizes bracket the paper's CNN (1.66M
+params) and per-leaf LM deltas.  No times: on this host the kernels run
+in the Pallas interpreter, whose wall time says nothing about the chip
+(the on-chip benchmark is ``bench/``).
 
 Traffic model (payload (n, p), element size B; A and the tau row are
 kilobytes and ignored):
@@ -40,26 +41,20 @@ mixing against the dense kernels on real block-diagonal topology
 matrices -- the A-operand footprint drops from O(n^2) to O(n d_max)
 (the ``bytes_A_*`` fields are informational, not baseline-gated).
 
-Plan overhead (``plan_overhead_rows``): host-side cost of the
-declarative trajectory object -- building a K-round
+Plan artifacts (``plan_overhead_rows``): a K-round
 ``RoundPlan.connectivity_aware`` (Algorithm 1's rule, all topology
-sampling included) plus its JSON round-trip.  Establishes that planning
-is microseconds-per-round host work, never on the device critical path,
-and sizes the pinned-trajectory artifacts ``benchmarks.run --plan``
-replays.
+sampling included) must survive its JSON round-trip; the rows size the
+pinned-trajectory artifacts ``benchmarks.run --plan`` replays.
 """
 
 from __future__ import annotations
-
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.fl import packing
-from repro.kernels.mixing.ops import (aggregate, aggregate_grouped,
-                                      aggregate_grouped_q, mix,
+from repro.kernels.mixing.ops import (aggregate, aggregate_grouped_q, mix,
                                       mix_aggregate, sparse_aggregate,
                                       sparse_mix)
 from repro.kernels.mixing.ref import mix_ref
@@ -111,14 +106,6 @@ def mesh_traffic_model(n_workers: int, p: int, n_leaves: int = 1) -> dict:
     )
 
 
-def _time(fn, reps=3):
-    fn()  # warm (compile / trace)
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        jax.block_until_ready(fn())
-    return (time.perf_counter() - t0) / reps * 1e6
-
-
 def grouped_payload_rows(quiet: bool = False):
     """MEASURED per-dtype payload bytes: the dtype-grouped packed layout
     (``repro.fl.packing``) vs the promoted one-buffer layout it replaced.
@@ -151,11 +138,6 @@ def grouped_payload_rows(quiet: bool = False):
                     for l in jax.tree.leaves(tree))
         # the one-buffer layout this replaced: every leaf at result_type
         promoted = packing.promoted_nbytes(spec, n)
-
-        A = jnp.eye(n, dtype=jnp.float32)
-        tau = jnp.ones(n, jnp.float32)
-        m = jnp.float32(n)
-        t_agg = _time(lambda: aggregate_grouped(A, tau, m, bufs))
         row = dict(kind="grouped_payload", layout=label, n=n,
                    n_groups=spec.n_groups,
                    group_dtypes=[str(jnp.dtype(g.dtype)) for g in
@@ -164,7 +146,6 @@ def grouped_payload_rows(quiet: bool = False):
                    bytes_ideal=int(ideal),
                    grouped_over_ideal=measured / ideal,
                    promoted_over_grouped=promoted / measured,
-                   us_agg_grouped_interp=t_agg,
                    kernel_launches=spec.n_groups)
         rows.append(row)
         if not quiet:
@@ -173,7 +154,7 @@ def grouped_payload_rows(quiet: bool = False):
                   f"promoted={promoted/1e6:7.3f}MB "
                   f"(x{promoted/measured:.2f} saved) "
                   f"ideal-overhead x{measured/ideal:.3f} "
-                  f"agg={t_agg:9.1f}us/{spec.n_groups} launches")
+                  f"{spec.n_groups} launches")
     return rows
 
 
@@ -189,7 +170,7 @@ def quant_payload_rows(quiet: bool = False):
     tree, int8 on the fp32 CNN tree) must land at <= 0.3x the grouped
     bytes; BENCH_mixing.json pins them via the CI baseline check.
     Parity: the fused dequant-epilogue aggregate kernel is checked
-    against the einsum oracle over the dequantized rows before timing.
+    against the einsum oracle over the dequantized rows.
     """
     from repro.fl.packing import QuantSpec
 
@@ -229,8 +210,6 @@ def quant_payload_rows(quiet: bool = False):
                             np.asarray(d, np.float32)) / float(n)
             np.testing.assert_allclose(np.asarray(g), ref,
                                        rtol=1e-5, atol=1e-5)
-        t_agg = _time(lambda: aggregate_grouped_q(A, tau, m, stored,
-                                                  scales, quant=quant))
 
         row = dict(kind="quant_payload", layout=label, n=n,
                    storage=storage, block=quant.block,
@@ -239,23 +218,20 @@ def quant_payload_rows(quiet: bool = False):
                    bytes_quantized=int(measured),
                    bytes_scales=int(qspec.scales_nbytes(n)),
                    ratio_vs_grouped=ratio,
-                   us_agg_quant_interp=t_agg,
                    kernel_launches=qspec.n_groups)
         rows.append(row)
         if not quiet:
             print(f"{label:18s} n={n:3d} {storage:4s} block={quant.block} "
                   f"grouped={grouped/1e6:7.3f}MB "
                   f"quantized={measured/1e6:7.3f}MB "
-                  f"(x{ratio:.3f}, scales {qspec.scales_nbytes(n)/1e3:.1f}KB) "
-                  f"agg={t_agg:9.1f}us")
+                  f"(x{ratio:.3f}, scales {qspec.scales_nbytes(n)/1e3:.1f}KB)")
     return rows
 
 
 def plan_overhead_rows(quiet: bool = False):
-    """Host-side RoundPlan cost: build (Algorithm 1 planning incl. all
-    topology/sampling draws), ``to_json``, and ``from_json`` wall time,
-    plus the serialized artifact size.  Pure host numpy -- no device
-    work -- so these are wall-clock rows, not baseline-gated fields."""
+    """RoundPlan artifacts: build (Algorithm 1 planning incl. all
+    topology/sampling draws), check the ``to_json`` / ``from_json``
+    round-trip, and report the serialized size (not baseline-gated)."""
     from repro.core.graphs import D2DNetwork
     from repro.core.server import ServerConfig
     from repro.fl.plan import RoundPlan
@@ -265,27 +241,14 @@ def plan_overhead_rows(quiet: bool = False):
                     (128, 8, 20)):
         net = D2DNetwork(n=n, c=c, k_range=(6, 9), p_fail=0.1)
         cfg = ServerConfig(t_max=K, phi_max=0.06, seed=0)
-
-        t0 = time.perf_counter()
         plan = RoundPlan.connectivity_aware(net, cfg)
-        t_build = (time.perf_counter() - t0) * 1e6
-
-        t0 = time.perf_counter()
         js = plan.to_json()
-        t_dump = (time.perf_counter() - t0) * 1e6
-        t0 = time.perf_counter()
-        back = RoundPlan.from_json(js)
-        t_load = (time.perf_counter() - t0) * 1e6
-        assert back.allclose(plan)
+        assert RoundPlan.from_json(js).allclose(plan)
 
         rows.append(dict(kind="plan_overhead", n=n, clusters=c, rounds=K,
-                         us_build=t_build, us_build_per_round=t_build / K,
-                         us_to_json=t_dump, us_from_json=t_load,
                          plan_json_bytes=len(js)))
         if not quiet:
             print(f"plan n={n:4d} c={c} K={K:3d}  "
-                  f"build={t_build:9.1f}us ({t_build / K:7.1f}us/round)  "
-                  f"to_json={t_dump:9.1f}us  from_json={t_load:9.1f}us  "
                   f"json={len(js) / 1e6:.2f}MB")
     return rows
 
@@ -298,11 +261,9 @@ def sparse_vs_dense_rows(quiet: bool = False):
     neighbor matrix stores ``n * d_max`` entries in ELL form (int32
     index + fp32 weight) against the dense ``n^2`` fp32 layout, so the
     operand footprint scales O(n) instead of O(n^2) -- the ratio below
-    is n/(2 d_max) and grows without bound.  Wall times are CPU times
-    (the Pallas kernels in interpret mode, the ELL mix an XLA gather)
-    and NOT baseline-gated (the new ``bytes_A_*`` fields are
-    informational, outside ``_BYTE_FIELDS``, so the committed gate is
-    untouched).
+    is n/(2 d_max) and grows without bound.  The ``bytes_A_*`` fields
+    are informational, outside ``_BYTE_FIELDS``, so the committed gate
+    is untouched; the dense and ELL aggregates are checked to agree.
     """
     from repro import topology
     from repro.core.adjacency import network_matrix, network_matrix_sparse
@@ -328,11 +289,9 @@ def sparse_vs_dense_rows(quiet: bool = False):
         np.testing.assert_allclose(np.asarray(sparse_mix(idx, w, X)),
                                    np.asarray(mix(A, X)),
                                    rtol=1e-4, atol=1e-4)
-
-        t_dense_mix = _time(lambda: mix(A, X))
-        t_sparse_mix = _time(lambda: sparse_mix(idx, w, X))
-        t_dense_agg = _time(lambda: aggregate(A, tau, m, X))
-        t_sparse_agg = _time(lambda: sparse_aggregate(idx, w, tau, m, X))
+        np.testing.assert_allclose(
+            np.asarray(sparse_aggregate(idx, w, tau, m, X)),
+            np.asarray(aggregate(A, tau, m, X)), rtol=1e-4, atol=1e-4)
 
         d_max = int(idx_np.shape[1])
         bytes_dense = n * n * 4
@@ -340,27 +299,21 @@ def sparse_vs_dense_rows(quiet: bool = False):
         row = dict(kind="sparse_vs_dense", n=n, clusters=c, p=p,
                    nnz=int(sp.nnz), d_max=d_max,
                    bytes_A_dense=bytes_dense, bytes_A_ell=bytes_ell,
-                   A_operand_ratio=bytes_dense / bytes_ell,
-                   us_mix_dense_interp=t_dense_mix,
-                   us_mix_sparse_interp=t_sparse_mix,
-                   us_agg_dense_interp=t_dense_agg,
-                   us_agg_sparse_interp=t_sparse_agg)
+                   A_operand_ratio=bytes_dense / bytes_ell)
         rows.append(row)
         if not quiet:
             print(f"n={n:5d} c={c:4d} p={p:6d} d_max={d_max:2d} "
                   f"A: dense={bytes_dense/1e6:8.3f}MB "
                   f"ell={bytes_ell/1e6:8.3f}MB "
-                  f"(x{bytes_dense/bytes_ell:6.1f})  "
-                  f"mix {t_dense_mix:9.1f}us->{t_sparse_mix:9.1f}us  "
-                  f"agg {t_dense_agg:9.1f}us->{t_sparse_agg:9.1f}us")
+                  f"(x{bytes_dense/bytes_ell:6.1f})")
     return rows
 
 
 def run(quiet: bool = False):
     rng = np.random.default_rng(0)
     rows = []
-    # interpret-mode (CPU) payloads; the kernels' BlockSpec tiling targets
-    # TPU VMEM where the paper's full 1.66M-param CNN payload applies.
+    # payloads the interpreter checks quickly; the kernels' BlockSpec
+    # tiling targets TPU VMEM where the full 1.66M-param CNN payload applies
     for n, p, dtype in ((70, 32_768, jnp.float32),
                         (70, 8_192, jnp.float32),
                         (16, 65_536, jnp.bfloat16),
@@ -383,34 +336,17 @@ def run(quiet: bool = False):
                                    rtol=atol, atol=atol)
         np.testing.assert_allclose(np.asarray(got_agg), ref_agg,
                                    rtol=atol, atol=atol)
-
-        # -- wall time (interpret mode): two-pass vs fused vs agg-only
-        # (jitted like the fused wrapper, so the comparison is end-to-end
-        # schedule vs schedule, not jit-dispatch overhead)
-        @jax.jit
-        def two_pass(A=A, X=X, tau=tau, m=m):
-            mixed = mix(A, X)
-            return jnp.einsum("i,ip->p", tau,
-                              mixed.astype(jnp.float32),
-                              preferred_element_type=jnp.float32) / m
-
-        t_ref = _time(lambda: mix_ref(A, X))
-        t_two = _time(two_pass)
-        t_fused = _time(lambda: mix_aggregate(A, tau, m, X))
-        t_agg = _time(lambda: aggregate(A, tau, m, X))
+        np.testing.assert_allclose(np.asarray(aggregate(A, tau, m, X)),
+                                   ref_agg, rtol=atol, atol=atol)
 
         model = traffic_model(n, p, np.dtype(dtype).itemsize)
         # cross-worker model: 8 workers (the CPU test mesh) moving this
         # row's p columns; _LM_LEAVES launches for the per-leaf schedule
         mesh = mesh_traffic_model(8, p, n_leaves=_LM_LEAVES)
         rows.append(dict(n=n, p=p, dtype=str(np.dtype(dtype).name),
-                         us_ref=t_ref, us_two_pass_interp=t_two,
-                         us_fused_interp=t_fused, us_agg_only_interp=t_agg,
                          match=True, **model, **mesh))
         if not quiet:
             print(f"n={n:3d} p={p:8d} {np.dtype(dtype).name:9s} "
-                  f"ref={t_ref:9.1f}us two-pass={t_two:9.1f}us "
-                  f"fused={t_fused:9.1f}us agg-only={t_agg:9.1f}us "
                   f"traffic x{model['traffic_ratio_fused']:.2f} "
                   f"(agg-only x{model['traffic_ratio_agg_only']:.2f})  OK")
 
@@ -436,7 +372,7 @@ def run(quiet: bool = False):
               "matrices (ELL A-operand bytes vs the (n, n) layout)")
     rows.extend(sparse_vs_dense_rows(quiet=quiet))
     if not quiet:
-        print("\nhost-side RoundPlan overhead (build + JSON round-trip)")
+        print("\nRoundPlan artifacts (JSON round-trip size)")
     rows.extend(plan_overhead_rows(quiet=quiet))
     return rows
 

@@ -125,8 +125,10 @@ def client_deltas(loss_fn: LossFn, global_params: PyTree,
     leading axis n_clients.
     """
     run = functools.partial(local_sgd, loss_fn)
-    finals = jax.vmap(lambda b: run(global_params, b, eta))(client_batches)
-    return jax.tree.map(lambda f, g: f - g[None], finals, global_params)
+    with jax.named_scope("local_sgd"):
+        finals = jax.vmap(lambda b: run(global_params, b, eta))(
+            client_batches)
+        return jax.tree.map(lambda f, g: f - g[None], finals, global_params)
 
 
 def mask_clients(tree: PyTree, active: jnp.ndarray) -> PyTree:
@@ -175,7 +177,8 @@ def global_update(global_params: PyTree, mixed: PyTree, tau: jnp.ndarray,
                          preferred_element_type=jnp.float32) / m
         return (g + agg.reshape(g.shape)).astype(g.dtype)
 
-    return jax.tree.map(upd, global_params, mixed)
+    with jax.named_scope("global_update"):
+        return jax.tree.map(upd, global_params, mixed)
 
 
 def fused_mix_update(global_params: PyTree, deltas: PyTree, A: jnp.ndarray,
@@ -438,11 +441,12 @@ def make_round_fn(loss_fn: LossFn, jit: bool = True,
                     "the returned new_qstate into the next round")
             deltas = client_deltas(loss_fn, global_params, client_batches,
                                    eta)
-            return _mix_and_update_quant(
-                global_params, deltas, A, tau, m,
-                mixing_backend=mixing_backend, chunk=chunk,
-                interpret=interpret, active=active, quant=quant,
-                qstate=qstate)
+            with jax.named_scope("mix"):
+                return _mix_and_update_quant(
+                    global_params, deltas, A, tau, m,
+                    mixing_backend=mixing_backend, chunk=chunk,
+                    interpret=interpret, active=active, quant=quant,
+                    qstate=qstate)
 
         return jax.jit(round_fn_q) if jit else round_fn_q
 
@@ -452,9 +456,11 @@ def make_round_fn(loss_fn: LossFn, jit: bool = True,
                  active: Optional[jnp.ndarray] = None
                  ) -> Tuple[PyTree, PyTree]:
         deltas = client_deltas(loss_fn, global_params, client_batches, eta)
-        return _mix_and_update(global_params, deltas, A, tau, m,
-                               mixing_backend=mixing_backend, chunk=chunk,
-                               interpret=interpret, active=active)
+        with jax.named_scope("mix"):
+            return _mix_and_update(global_params, deltas, A, tau, m,
+                                   mixing_backend=mixing_backend,
+                                   chunk=chunk, interpret=interpret,
+                                   active=active)
 
     return jax.jit(round_fn) if jit else round_fn
 

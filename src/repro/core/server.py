@@ -50,6 +50,8 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
+from repro.spans import span
+
 from .metrics import CommLedger
 
 __all__ = ["ServerConfig", "RoundRecord", "History", "FederatedServer"]
@@ -221,10 +223,12 @@ class FederatedServer:
             raise ValueError(
                 f"plan is for {plan.n_clients} clients, network has "
                 f"{self.network.n}")
-        batches = [self.batch_sampler(self.rng, t)
-                   for t in range(plan.n_rounds)]
+        with span("server.batches"):
+            batches = [self.batch_sampler(self.rng, t)
+                       for t in range(plan.n_rounds)]
         return plan, batches
 
+    @span("server.run")
     def run(self, eval_fn: Optional[EvalFn] = None, eval_every: int = 1,
             plan=None, controller=None) -> History:
         """build plan -> engine.execute(plan) -> History.
@@ -267,8 +271,9 @@ class FederatedServer:
                                                 "sparse_aggregate")
             loop = ControlLoop(self.network, self.config, controller,
                                algorithm=self.algorithm, sparse=sparse)
-            batches = [self.batch_sampler(self.rng, t)
-                       for t in range(self.config.t_max)]
+            with span("server.batches"):
+                batches = [self.batch_sampler(self.rng, t)
+                           for t in range(self.config.t_max)]
             self.params, history = execute_controlled(
                 loop, self.params, batches, eval_fn=eval_fn,
                 eval_every=eval_every,

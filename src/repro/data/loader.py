@@ -17,6 +17,8 @@ from typing import List, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.spans import span
+
 from .synthetic import Dataset
 
 __all__ = ["FederatedBatcher", "lm_batches"]
@@ -34,6 +36,7 @@ class FederatedBatcher:
     def n_clients(self) -> int:
         return len(self.parts)
 
+    @span("data.batch")
     def __call__(self, rng: np.random.Generator, t: int
                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Returns (x, y) with shapes (n, T, B, ...) / (n, T, B)."""

@@ -60,6 +60,7 @@ from repro.core.rounds import MIXING_BACKENDS, QUANT_BACKENDS, \
     make_round_fn, make_scanned_rounds
 from repro.core.server import History, RoundRecord
 from repro.core.sparse import SparseAseq
+from repro.spans import span
 from .distributed import MIXINGS, make_scanned_train_steps, make_train_step
 from .plan import RoundPlan
 
@@ -303,8 +304,9 @@ def _append_record(plan: RoundPlan, history: History, t: int, get_params,
     rec = _record(plan, t)
     if eval_fn is not None and (t % eval_every == 0
                                 or t == plan.n_rounds - 1):
-        rec.metrics = {k: float(v)
-                       for k, v in eval_fn(get_params()).items()}
+        with span("engine.eval", round=rec.t):
+            rec.metrics = {k: float(v)
+                           for k, v in eval_fn(get_params()).items()}
     history.records.append(rec)
     history.ledger.add_round(d2s=rec.d2s, d2d=rec.d2d)
 
@@ -341,45 +343,54 @@ class LocalEngine:
         cfg = self.cfg
         K = plan.n_rounds
         sparse = self.backend in ("sparse", "sparse_aggregate")
-        A_seq, tau_seq, m_seq, eta_seq, active_seq = _device_columns(
-            plan, sparse=sparse)
-        history = History(algorithm=plan.algorithm,
-                          ledger=CommLedger(energy_ratio=energy_ratio))
-        quant, qstate = _quant_setup(cfg, plan, params, self.backend)
+        with span("engine.prepare"):
+            A_seq, tau_seq, m_seq, eta_seq, active_seq = _device_columns(
+                plan, sparse=sparse)
+            history = History(algorithm=plan.algorithm,
+                              ledger=CommLedger(energy_ratio=energy_ratio))
+            quant, qstate = _quant_setup(cfg, plan, params, self.backend)
+            if cfg.scan:
+                scanned = make_scanned_rounds(
+                    self.loss_fn, K, jit=cfg.jit,
+                    mixing_backend=self.backend, chunk=cfg.chunk,
+                    interpret=cfg.interpret, quant=quant)
+                batches_seq = jax.tree.map(lambda *bs: jnp.stack(bs),
+                                           *batches)
+            else:
+                round_fn = make_round_fn(self.loss_fn, jit=cfg.jit,
+                                         mixing_backend=self.backend,
+                                         chunk=cfg.chunk,
+                                         interpret=cfg.interpret,
+                                         quant=quant)
 
         if cfg.scan:
-            scanned = make_scanned_rounds(
-                self.loss_fn, K, jit=cfg.jit, mixing_backend=self.backend,
-                chunk=cfg.chunk, interpret=cfg.interpret, quant=quant)
-            batches_seq = jax.tree.map(lambda *bs: jnp.stack(bs), *batches)
-            if quant is not None:
-                params, params_seq, _ = scanned(params, batches_seq, A_seq,
-                                                tau_seq, m_seq, eta_seq,
-                                                active_seq, qstate)
-            else:
-                params, params_seq = scanned(params, batches_seq, A_seq,
-                                             tau_seq, m_seq, eta_seq,
-                                             active_seq)
+            with span("engine.dispatch", round=plan.t0):
+                if quant is not None:
+                    params, params_seq, _ = scanned(
+                        params, batches_seq, A_seq, tau_seq, m_seq,
+                        eta_seq, active_seq, qstate)
+                else:
+                    params, params_seq = scanned(
+                        params, batches_seq, A_seq, tau_seq, m_seq,
+                        eta_seq, active_seq)
             _fill_history(plan, history,
                           lambda t: jax.tree.map(lambda x: x[t], params_seq),
                           eval_fn, eval_every)
             return params, history
 
-        round_fn = make_round_fn(self.loss_fn, jit=cfg.jit,
-                                 mixing_backend=self.backend,
-                                 chunk=cfg.chunk, interpret=cfg.interpret,
-                                 quant=quant)
         for t in range(K):
-            A_arg = ((A_seq[0][t], A_seq[1][t]) if sparse else A_seq[t])
-            args = (params, batches[t], A_arg, tau_seq[t], m_seq[t],
-                    eta_seq[t])
-            if active_seq is not None or quant is not None:
-                args = args + (active_seq[t] if active_seq is not None
-                               else None,)
-            if quant is not None:
-                params, _, qstate = round_fn(*args, qstate)
-            else:
-                params, _ = round_fn(*args)
+            with span("engine.dispatch", round=plan.t0 + t):
+                A_arg = ((A_seq[0][t], A_seq[1][t]) if sparse
+                         else A_seq[t])
+                args = (params, batches[t], A_arg, tau_seq[t], m_seq[t],
+                        eta_seq[t])
+                if active_seq is not None or quant is not None:
+                    args = args + (active_seq[t] if active_seq is not None
+                                   else None,)
+                if quant is not None:
+                    params, _, qstate = round_fn(*args, qstate)
+                else:
+                    params, _ = round_fn(*args)
             # record inline: only the current round's params stay live
             _append_record(plan, history, t, lambda p=params: p,
                            eval_fn, eval_every)

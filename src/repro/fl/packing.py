@@ -385,9 +385,10 @@ def apply_aggregate_row(global_params: PyTree,
     """Eq.-4 epilogue shared by every one-pass backend: unpack the
     per-group fp32 aggregate rows and add them leaf-wise, casting back to
     each global-param leaf's dtype only after the add."""
-    agg = unpack_row(rows, spec)
-    return jax.tree.map(lambda g, a: (g + a).astype(g.dtype),
-                        global_params, agg)
+    with jax.named_scope("global_update"):
+        agg = unpack_row(rows, spec)
+        return jax.tree.map(lambda g, a: (g + a).astype(g.dtype),
+                            global_params, agg)
 
 
 # ---------------------------------------------------------------------------

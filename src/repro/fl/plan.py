@@ -97,6 +97,7 @@ from repro.core.bounds import exact_phi_ell, phi_ell_bound_from_stats, \
 from repro.core.graphs import SparseClusterGraph
 from repro.core.metrics import count_d2d_transmissions
 from repro.core.sparse import SparseA, SparseAseq
+from repro.spans import span
 from repro.topology import TopologySpec
 
 from . import faults as _faults
@@ -399,6 +400,7 @@ class RoundPlan:
         )
 
     @classmethod
+    @span("plan.build")
     def _planned(cls, network, config, algorithm,
                  rng: Optional[np.random.Generator],
                  sparse: bool = False) -> "RoundPlan":

@@ -141,4 +141,5 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((bq,), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

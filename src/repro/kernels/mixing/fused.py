@@ -102,6 +102,7 @@ def mix_aggregate_pallas(A: jnp.ndarray, w: jnp.ndarray, X: jnp.ndarray, *,
             jax.ShapeDtypeStruct((s, p), jnp.float32),
         ],
         interpret=interpret,
+        name="mix_aggregate",
     )(A, w, X)
 
 
@@ -125,6 +126,7 @@ def aggregate_pallas(w: jnp.ndarray, X: jnp.ndarray, *, chunk: int = 2048,
         out_specs=pl.BlockSpec((s, chunk), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((s, p), jnp.float32),
         interpret=interpret,
+        name="aggregate",
     )(w, X)
 
 
@@ -239,6 +241,7 @@ def mix_aggregate_dequant_pallas(A: jnp.ndarray, w: jnp.ndarray,
             jax.ShapeDtypeStruct((s, p), jnp.float32),
         ],
         interpret=interpret,
+        name="mix_aggregate_q",
     )(A, w, Xq, S_tiles)
 
 
@@ -266,4 +269,5 @@ def aggregate_dequant_pallas(w: jnp.ndarray, Xq: jnp.ndarray,
         out_specs=pl.BlockSpec((s, chunk), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((s, p), jnp.float32),
         interpret=interpret,
+        name="aggregate_q",
     )(w, Xq, S_tiles)

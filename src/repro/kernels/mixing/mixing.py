@@ -70,4 +70,5 @@ def mix_pallas(A: jnp.ndarray, X: jnp.ndarray, *, chunk: int = 2048,
         out_specs=pl.BlockSpec((n, chunk), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, p), X.dtype),
         interpret=interpret,
+        name="mix",
     )(A, X)

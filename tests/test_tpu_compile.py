@@ -9,6 +9,7 @@ time; the interpret-mode suites pin the results.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -119,3 +120,51 @@ def test_flash_attention_compiles_hd128(sds):
     q = sds((1, 8, 2048, 128), jnp.bfloat16)
     kv = sds((1, 2, 2048, 128), jnp.bfloat16)
     assert "tpu_custom_call" in _compiled_text(fn, q, kv, kv)
+
+
+def _named(kernel, *args):
+    """The HLO instruction of the one Mosaic call that ``kernel`` makes,
+    compiled inside a caller of another name."""
+    def caller(*a):
+        return kernel(*a)
+
+    calls = [re.search(r"%([\w.-]+) = ", line).group(1)
+             for line in _compiled_text(caller, *args).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, calls
+    return calls[0].rsplit(".", 1)[0]
+
+
+@pytest.mark.parametrize("op", ["aggregate", "mix", "mix_aggregate",
+                                "aggregate_q", "mix_aggregate_q",
+                                "flash_attention"])
+def test_each_kernel_is_named_after_its_op(sds, op):
+    """The trace finds a kernel by its own name (``pallas_call(name=)``),
+    whatever function calls it."""
+    p = 2 * CHUNK
+    q = QuantSpec(storage="int8", block=BLOCK)
+    xq = sds((N_PAD, q.stored_cols(p)), q.storage_dtype)
+    s = sds((N_PAD, p // BLOCK))
+    w, A, X = sds((8, N_PAD)), sds((N_PAD, N_PAD)), sds((N_PAD, p))
+    kernels = {
+        "aggregate": (functools.partial(aggregate_pallas, chunk=CHUNK,
+                                        interpret=False), w, X),
+        "mix": (functools.partial(mix_pallas, chunk=CHUNK,
+                                  interpret=False), A, X),
+        "mix_aggregate": (functools.partial(
+            mix_aggregate_pallas, chunk=CHUNK, interpret=False), A, w, X),
+        "aggregate_q": (functools.partial(
+            aggregate_dequant_pallas, storage="int8", block=BLOCK,
+            chunk=CHUNK, interpret=False), w, xq, s),
+        "mix_aggregate_q": (functools.partial(
+            mix_aggregate_dequant_pallas, storage="int8", block=BLOCK,
+            chunk=CHUNK, interpret=False), A, w, xq, s),
+        "flash_attention": (functools.partial(
+            flash_attention_pallas, causal=True, window=None,
+            true_seq_k=256, bq=128, bk=128, interpret=False),
+            sds((1, 2, 256, 128), jnp.bfloat16),
+            sds((1, 1, 256, 128), jnp.bfloat16),
+            sds((1, 1, 256, 128), jnp.bfloat16)),
+    }
+    kernel, *args = kernels[op]
+    assert _named(kernel, *args) == op
