@@ -104,20 +104,52 @@ def softmax_xent(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     return -jnp.take_along_axis(logp, labels[..., None], axis=-1).mean()
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def l2_regularized_loss(apply_fn, params: PyTree, batch, mu: float = 1e-2
                         ) -> jnp.ndarray:
     """mu-strongly-convex loss (cross-entropy + (mu/2)||params||^2) --
-    satisfies Assumption 1 exactly for the logistic-regression head."""
+    satisfies Assumption 1 exactly for the logistic-regression head.
+
+    One compiled program per call, keyed on ``apply_fn`` (static, so a
+    caller should build it once, not a new closure per call) and the
+    shapes and dtypes of ``params`` and ``batch``; ``mu`` is traced.
+    Inside another trace (the round program's ``grad``/``vmap``) the call
+    is inlined.  ``l2_regularized_loss.__wrapped__`` is the plain
+    function."""
     x, y = batch
     ce = softmax_xent(apply_fn(params, x), y)
     sq = sum(jnp.sum(jnp.square(p)) for p in jax.tree.leaves(params))
     return ce + 0.5 * mu * sq
 
 
+@functools.partial(jax.jit, static_argnums=(0, 4))
+def _accuracy_hits(apply_fn, params: PyTree, x: jnp.ndarray, y: jnp.ndarray,
+                   batch: int) -> jnp.ndarray:
+    """The int32 count of correct argmax predictions, ``batch`` samples
+    at a time: ``lax.map`` over the full batches, then the remainder."""
+    def count(xb, yb):
+        return jnp.sum(jnp.argmax(apply_fn(params, xb), -1) == yb,
+                       dtype=jnp.int32)
+
+    k = len(y) // batch
+    hits = jnp.int32(0)
+    if k:
+        cut = k * batch
+        xk = x[:cut].reshape(k, batch, *x.shape[1:])
+        yk = y[:cut].reshape(k, batch)
+        hits += jax.lax.map(lambda b: count(*b), (xk, yk)).sum()
+    if len(y) > k * batch:
+        hits += count(x[k * batch:], y[k * batch:])
+    return hits
+
+
 def accuracy(apply_fn, params: PyTree, x: jnp.ndarray, y: jnp.ndarray,
              batch: int = 512) -> float:
-    hits = 0
-    for i in range(0, len(y), batch):
-        logits = apply_fn(params, x[i:i + batch])
-        hits += int((jnp.argmax(logits, -1) == y[i:i + batch]).sum())
-    return hits / len(y)
+    """Share of ``y`` that ``apply_fn``'s argmax predicts, with the logits
+    of at most ``batch`` samples alive at a time.
+
+    One compiled program and one host transfer per call, keyed on
+    ``apply_fn`` and ``batch`` (both static: build ``apply_fn`` once, not
+    a new closure per call) and the shapes and dtypes of ``params``,
+    ``x`` and ``y``."""
+    return int(_accuracy_hits(apply_fn, params, x, y, batch)) / len(y)

@@ -1,6 +1,9 @@
 """Tests: data pipeline, optimizers, checkpointing, paper CNN."""
 
+import collections
 import os
+import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -8,10 +11,12 @@ import numpy as np
 import pytest
 from hypothesis_compat import given, settings, strategies as st
 
+from repro import spans
 from repro.ckpt import latest_checkpoint, load_checkpoint, save_checkpoint
 from repro.data import (Dataset, FederatedBatcher, dirichlet_partition,
                         iid_partition, label_sorted_partition,
                         make_classification, make_token_stream, lm_batches)
+from repro.core.rounds import make_round_fn
 from repro.models.cnn import (accuracy, cnn_apply, init_cnn, init_logreg,
                               init_mlp, l2_regularized_loss, logreg_apply,
                               mlp_apply, softmax_xent)
@@ -227,3 +232,102 @@ def test_l2_regularized_loss_strongly_convex_grad():
     sq = sum(jnp.sum((u - v) ** 2) for u, v in zip(
         jax.tree.leaves(params_a), jax.tree.leaves(params_b)))
     assert float(inner) >= mu * float(sq) - 1e-6
+
+
+# ---------------------------------------------------------------------------
+# Eval programs: accuracy and the test loss, one compiled program per call
+# ---------------------------------------------------------------------------
+
+MODELS = {"cnn": (init_cnn, cnn_apply), "mlp": (init_mlp, mlp_apply),
+          "logreg": (init_logreg, logreg_apply)}
+
+
+def _eager_hits(apply, params, x, y, batch):
+    hits = 0
+    for i in range(0, len(y), batch):
+        logits = apply(params, x[i:i + batch])
+        hits += int((jnp.argmax(logits, -1) == y[i:i + batch]).sum())
+    return hits
+
+
+@pytest.mark.parametrize("n", [1100, 1024])
+@pytest.mark.parametrize("model", sorted(MODELS))
+def test_accuracy_matches_eager_per_batch_count(model, n):
+    """1100 = 2 full batches of 512 and a remainder of 76; 1024 = 2."""
+    init, apply = MODELS[model]
+    ds = make_classification(n_samples=n, seed=5)
+    x, y = jnp.asarray(ds.x), jnp.asarray(ds.y)
+    params = init(seed=3)
+    assert accuracy(apply, params, x, y, batch=512) == (
+        _eager_hits(apply, params, x, y, 512) / n)
+
+
+@pytest.mark.parametrize("model", sorted(MODELS))
+def test_l2_regularized_loss_matches_unjitted(model):
+    init, apply = MODELS[model]
+    ds = make_classification(n_samples=32, seed=6)
+    batch = (jnp.asarray(ds.x), jnp.asarray(ds.y))
+    params = init(seed=4)
+    plain = l2_regularized_loss.__wrapped__
+    np.testing.assert_allclose(
+        float(l2_regularized_loss(apply, params, batch, mu=0.05)),
+        float(plain(apply, params, batch, mu=0.05)), rtol=1e-6, atol=1e-6)
+    g = jax.grad(lambda p: l2_regularized_loss(apply, p, batch, mu=0.05))(
+        params)
+    g0 = jax.grad(lambda p: plain(apply, p, batch, mu=0.05))(params)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(g0)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
+
+
+def test_eval_second_call_compiles_nothing():
+    """The first call of each eval function compiles one program; a
+    second with the same ``apply_fn`` and shapes compiles nothing."""
+    ds = make_classification(n_samples=333, seed=7)   # shapes of this test
+    x, y = jnp.asarray(ds.x), jnp.asarray(ds.y)
+    params = init_cnn(seed=2)
+
+    def eval_once():
+        return (accuracy(cnn_apply, params, x, y, batch=128),
+                float(l2_regularized_loss(cnn_apply, params, (x, y))))
+
+    first, second = spans.Recorder(), spans.Recorder()
+    with spans.recording(first):
+        a = eval_once()
+    with spans.recording(second):
+        b = eval_once()
+    assert a == b
+    compiles = {fun: int(count) for (event, fun), (_, count)
+                in first.counters.items()
+                if event == "/jax/core/compile/backend_compile_duration"}
+    assert compiles == {"jit(_accuracy_hits)": 1,
+                        "jit(l2_regularized_loss)": 1}
+    assert second.counters == {}
+
+
+def _opcodes(hlo_text):
+    return collections.Counter(
+        m.group(1) for m in re.finditer(
+            r"^\s*(?:ROOT\s+)?%[\w.\-]+ = .*?\s([a-z][\w\-]*)\(",
+            hlo_text, re.MULTILINE))
+
+
+def test_round_program_with_jitted_loss_does_the_same_work():
+    """The round program inlines the jitted loss: the same HLO ops and
+    FLOPs as a round built on the plain function."""
+    n, T, B, hw = 4, 2, 8, 12
+    params = init_cnn(seed=0, image_hw=hw)
+    batches = (jnp.zeros((n, T, B, hw, hw, 1)),
+               jnp.zeros((n, T, B), jnp.int32))
+    args = (params, batches, jnp.full((n, n), 1.0 / n), jnp.ones(n),
+            jnp.float32(n), jnp.float32(0.1))
+    compiled = {}
+    for name, loss in (("jit", l2_regularized_loss),
+                       ("plain", l2_regularized_loss.__wrapped__)):
+        round_fn = make_round_fn(partial(loss, cnn_apply, mu=1e-2),
+                                 mixing_backend="einsum")
+        compiled[name] = round_fn.lower(*args).compile()
+    assert _opcodes(compiled["jit"].as_text()) == _opcodes(
+        compiled["plain"].as_text())
+    assert compiled["jit"].cost_analysis()["flops"] == compiled[
+        "plain"].cost_analysis()["flops"]
