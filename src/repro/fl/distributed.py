@@ -437,12 +437,6 @@ def make_train_step(cfg: ModelConfig, mesh, mixing: str = "ring",
                                          prefix=(caxes,))
         cshard = _shardings(mesh, cspecs)
 
-        # 1. broadcast global -> per-client stacked params
-        per_client = jax.tree.map(
-            lambda g: jnp.broadcast_to(g[None], (n,) + g.shape),
-            global_params)
-        per_client = jax.lax.with_sharding_constraint(per_client, cshard)
-
         # 2. T local SGD steps per client (paper eq. (1))
         def one_client(p0, toks, pe):
             def step(p, xs):
@@ -460,54 +454,61 @@ def make_train_step(cfg: ModelConfig, mesh, mixing: str = "ring",
             pT, _ = jax.lax.scan(step, p0, xs)
             return pT
 
-        if client_impl == "vmap":
-            finals = jax.vmap(one_client)(
-                per_client, tokens,
-                prefix if prefix is not None else None) \
-                if prefix is not None else jax.vmap(
-                    lambda p0, t: one_client(p0, t, None))(per_client,
-                                                           tokens)
-        else:
-            # partial shard_map: client axes manual (each shard sees ONE
-            # client, squeezed), 'model' axis stays automatic so nested
-            # manual collectives (SP-MLP, EP-MoE) can claim it.
-            sq = lambda t: jax.tree.map(lambda a: a[0], t)       # noqa: E731
-            ex = lambda t: jax.tree.map(lambda a: a[None], t)    # noqa: E731
-            cax_spec = P(caxes)
+        with jax.named_scope("local_sgd"):
+            # 1. broadcast global -> per-client stacked params
+            per_client = jax.tree.map(
+                lambda g: jnp.broadcast_to(g[None], (n,) + g.shape),
+                global_params)
+            per_client = jax.lax.with_sharding_constraint(per_client, cshard)
 
-            def spec_of(tree, extra):
-                return jax.tree.map(
-                    lambda _: P(*((caxes,) + (None,) * extra)), tree)
-
-            if prefix is None:
-                body = lambda p0, t: ex(                         # noqa: E731
-                    one_client(sq(p0), sq(t), None))
-                in_specs = (
-                    jax.tree.map(lambda a: P(*((caxes,)
-                                               + (None,) * (a.ndim - 1))),
-                                 per_client),
-                    P(caxes, None, None, None))
-                finals = _shard_map(
-                    body, mesh, in_specs=in_specs,
-                    out_specs=in_specs[0],
-                    axis_names=set(caxes))(per_client, tokens)
+            if client_impl == "vmap":
+                finals = jax.vmap(one_client)(
+                    per_client, tokens,
+                    prefix if prefix is not None else None) \
+                    if prefix is not None else jax.vmap(
+                        lambda p0, t: one_client(p0, t, None))(per_client,
+                                                               tokens)
             else:
-                body = lambda p0, t, pe: ex(                     # noqa: E731
-                    one_client(sq(p0), sq(t), sq(pe)))
-                pspec = jax.tree.map(
-                    lambda a: P(*((caxes,) + (None,) * (a.ndim - 1))),
-                    per_client)
-                finals = _shard_map(
-                    body, mesh,
-                    in_specs=(pspec, P(caxes, None, None, None),
-                              P(caxes, None, None, None, None)),
-                    out_specs=pspec,
-                    axis_names=set(caxes))(per_client, tokens, prefix)
-        finals = jax.lax.with_sharding_constraint(finals, cshard)
+                # partial shard_map: client axes manual (each shard sees ONE
+                # client, squeezed), 'model' axis stays automatic so nested
+                # manual collectives (SP-MLP, EP-MoE) can claim it.
+                sq = lambda t: jax.tree.map(lambda a: a[0], t)   # noqa: E731
+                ex = lambda t: jax.tree.map(lambda a: a[None], t)  # noqa: E731
+                cax_spec = P(caxes)
 
-        # scaled cumulative gradients x_i^{(t,T)} - x^{(t)}
-        deltas = jax.tree.map(lambda f, g: f - g[None], finals,
-                              global_params)
+                def spec_of(tree, extra):
+                    return jax.tree.map(
+                        lambda _: P(*((caxes,) + (None,) * extra)), tree)
+
+                if prefix is None:
+                    body = lambda p0, t: ex(                     # noqa: E731
+                        one_client(sq(p0), sq(t), None))
+                    in_specs = (
+                        jax.tree.map(lambda a: P(*((caxes,)
+                                                   + (None,) * (a.ndim - 1))),
+                                     per_client),
+                        P(caxes, None, None, None))
+                    finals = _shard_map(
+                        body, mesh, in_specs=in_specs,
+                        out_specs=in_specs[0],
+                        axis_names=set(caxes))(per_client, tokens)
+                else:
+                    body = lambda p0, t, pe: ex(                 # noqa: E731
+                        one_client(sq(p0), sq(t), sq(pe)))
+                    pspec = jax.tree.map(
+                        lambda a: P(*((caxes,) + (None,) * (a.ndim - 1))),
+                        per_client)
+                    finals = _shard_map(
+                        body, mesh,
+                        in_specs=(pspec, P(caxes, None, None, None),
+                                  P(caxes, None, None, None, None)),
+                        out_specs=pspec,
+                        axis_names=set(caxes))(per_client, tokens, prefix)
+            finals = jax.lax.with_sharding_constraint(finals, cshard)
+
+            # scaled cumulative gradients x_i^{(t,T)} - x^{(t)}
+            deltas = jax.tree.map(lambda f, g: f - g[None], finals,
+                                  global_params)
 
         # 3.+4. D2D mixing + D2S sampled aggregation
         if quant is not None and qstate is None:
@@ -515,10 +516,11 @@ def make_train_step(cfg: ModelConfig, mesh, mixing: str = "ring",
                 "quantized train_step needs the quantizer state: build it "
                 "with packing.init_quant_state(spec, n) and thread the "
                 "returned new_qstate into the next step")
-        return _mix_and_aggregate(mesh, mixing, deltas, A, tau, m,
-                                  global_params, msize, zero=zero,
-                                  active=active, quant=quant,
-                                  qstate=qstate)
+        with jax.named_scope("mix"):
+            return _mix_and_aggregate(mesh, mixing, deltas, A, tau, m,
+                                      global_params, msize, zero=zero,
+                                      active=active, quant=quant,
+                                      qstate=qstate)
 
     if not jit:
         return train_step

@@ -487,43 +487,49 @@ class MeshEngine:
         _check_batches(plan, batches)
         cfg = self.cfg
         K = plan.n_rounds
-        A_seq, tau_seq, m_seq, eta_seq, active_seq = _device_columns(plan)
-        history = History(algorithm=plan.algorithm,
-                          ledger=CommLedger(energy_ratio=energy_ratio))
-        quant, qstate = _quant_setup(cfg, plan, params, self.backend,
-                                     mesh=cfg.mesh)
+        with span("engine.prepare"):
+            A_seq, tau_seq, m_seq, eta_seq, active_seq = _device_columns(
+                plan)
+            history = History(algorithm=plan.algorithm,
+                              ledger=CommLedger(energy_ratio=energy_ratio))
+            quant, qstate = _quant_setup(cfg, plan, params, self.backend,
+                                         mesh=cfg.mesh)
+            if cfg.scan:
+                scanned = make_scanned_train_steps(
+                    cfg.model_cfg, cfg.mesh, K, mixing=self.backend,
+                    jit=cfg.jit, quant=quant)
+                tokens_seq = jax.tree.map(lambda *bs: jnp.stack(bs),
+                                          *batches)
+            else:
+                step = make_train_step(cfg.model_cfg, cfg.mesh,
+                                       mixing=self.backend, jit=cfg.jit,
+                                       quant=quant)
 
         if cfg.scan:
-            scanned = make_scanned_train_steps(
-                cfg.model_cfg, cfg.mesh, K, mixing=self.backend,
-                jit=cfg.jit, quant=quant)
-            tokens_seq = jax.tree.map(lambda *bs: jnp.stack(bs), *batches)
-            if quant is not None:
-                params, params_seq, _ = scanned(params, tokens_seq, A_seq,
-                                                tau_seq, m_seq, eta_seq,
-                                                active_seq=active_seq,
-                                                qstate=qstate)
-            else:
-                params, params_seq = scanned(params, tokens_seq, A_seq,
-                                             tau_seq, m_seq, eta_seq,
-                                             active_seq=active_seq)
+            with span("engine.dispatch", round=plan.t0):
+                if quant is not None:
+                    params, params_seq, _ = scanned(
+                        params, tokens_seq, A_seq, tau_seq, m_seq, eta_seq,
+                        active_seq=active_seq, qstate=qstate)
+                else:
+                    params, params_seq = scanned(
+                        params, tokens_seq, A_seq, tau_seq, m_seq, eta_seq,
+                        active_seq=active_seq)
             _fill_history(plan, history,
                           lambda t: jax.tree.map(lambda x: x[t], params_seq),
                           eval_fn, eval_every)
             return params, history
 
-        step = make_train_step(cfg.model_cfg, cfg.mesh,
-                               mixing=self.backend, jit=cfg.jit,
-                               quant=quant)
         for t in range(K):
-            kw = {} if active_seq is None else {"active": active_seq[t]}
-            if quant is not None:
-                params, qstate = step(params, batches[t], A_seq[t],
-                                      tau_seq[t], m_seq[t], eta_seq[t],
-                                      qstate=qstate, **kw)
-            else:
-                params = step(params, batches[t], A_seq[t], tau_seq[t],
-                              m_seq[t], eta_seq[t], **kw)
+            with span("engine.dispatch", round=plan.t0 + t):
+                kw = {} if active_seq is None else {"active": active_seq[t]}
+                if quant is not None:
+                    params, qstate = step(params, batches[t], A_seq[t],
+                                          tau_seq[t], m_seq[t], eta_seq[t],
+                                          qstate=qstate, **kw)
+                else:
+                    params = step(params, batches[t], A_seq[t], tau_seq[t],
+                                  m_seq[t], eta_seq[t], **kw)
             _append_record(plan, history, t, lambda p=params: p,
                            eval_fn, eval_every)
         return params, history

@@ -26,7 +26,7 @@ from . import mla as mla_mod
 from . import moe as moe_mod
 from . import ssm as ssm_mod
 from .config import ModelConfig
-from .layers import mlp_apply, mlp_apply_sp, mlp_init, norm
+from .layers import mlp_apply, mlp_apply_sp, mlp_init, norm, norm_init
 from .sharding import constrain_seq, sp_mlp_axis
 
 PyTree = Any
@@ -42,8 +42,8 @@ __all__ = ["stack_init", "stack_forward", "stack_prefill", "stack_decode",
 def transformer_block_init(key, cfg: ModelConfig, dtype,
                            is_moe: bool) -> PyTree:
     k1, k2 = jax.random.split(key)
-    p = {"ln1": jnp.ones((cfg.d_model,), dtype),
-         "ln2": jnp.ones((cfg.d_model,), dtype)}
+    p = {"ln1": norm_init(cfg.d_model, cfg.norm_type, dtype),
+         "ln2": norm_init(cfg.d_model, cfg.norm_type, dtype)}
     if cfg.mla:
         p["mla"] = mla_mod.mla_init(k1, cfg, dtype)
     else:
@@ -56,7 +56,7 @@ def transformer_block_init(key, cfg: ModelConfig, dtype,
 
 
 def mamba_block_init(key, cfg: ModelConfig, dtype) -> PyTree:
-    return {"ln1": jnp.ones((cfg.d_model,), dtype),
+    return {"ln1": norm_init(cfg.d_model, cfg.norm_type, dtype),
             "ssm": ssm_mod.ssm_init(key, cfg, dtype)}
 
 

@@ -207,12 +207,13 @@ class ModelConfig:
             p += di * d                          # out projection
             return p
 
-        per_layer_norms = 2 * d
-        total = emb + head + d  # final norm
+        norm_p = 2 * d if self.norm_type == "layer" else d  # scale [+ shift]
+        per_layer_norms = 2 * norm_p
+        total = emb + head + norm_p  # final norm
         if self.family == "ssm":
-            total += self.n_layers * (mamba_params() + d)
+            total += self.n_layers * (mamba_params() + norm_p)
         elif self.family == "hybrid":
-            total += self.n_layers * (mamba_params() + d)
+            total += self.n_layers * (mamba_params() + norm_p)
             n_shared = max(self.n_layers // self.hybrid_attn_every, 1)
             total += attn_params() + mlp_params(self.d_ff) + per_layer_norms
         else:

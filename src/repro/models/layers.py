@@ -10,7 +10,8 @@ import jax.numpy as jnp
 
 PyTree = Any
 
-__all__ = ["rms_norm", "layer_norm", "norm", "rope_angles", "apply_rope",
+__all__ = ["rms_norm", "layer_norm", "norm", "norm_init", "rope_angles",
+           "apply_rope",
            "mlp_init", "mlp_apply", "dense_init", "he_normal", "lecun_normal"]
 
 
@@ -47,17 +48,31 @@ def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6):
             ).astype(dt)
 
 
-def layer_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6):
+def layer_norm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
+               eps: float = 1e-6):
+    """``torch.nn.LayerNorm``: a learned scale and shift (bias)."""
     dt = x.dtype
     x32 = x.astype(jnp.float32)
     mean = jnp.mean(x32, axis=-1, keepdims=True)
     var = jnp.var(x32, axis=-1, keepdims=True)
     return (((x32 - mean) * jax.lax.rsqrt(var + eps))
-            * scale.astype(jnp.float32)).astype(dt)
+            * scale.astype(jnp.float32) + bias.astype(jnp.float32)
+            ).astype(dt)
 
 
-def norm(x, scale, kind: str = "rms", eps: float = 1e-6):
-    return rms_norm(x, scale, eps) if kind == "rms" else layer_norm(x, scale, eps)
+def norm_init(d: int, kind: str = "rms", dtype=jnp.float32) -> PyTree:
+    """A norm's params: the scale alone for RMS norm, ``{"scale",
+    "bias"}`` (ones and zeros) for layer norm."""
+    if kind == "rms":
+        return jnp.ones((d,), dtype)
+    return {"scale": jnp.ones((d,), dtype), "bias": jnp.zeros((d,), dtype)}
+
+
+def norm(x, p, kind: str = "rms", eps: float = 1e-6):
+    """``p`` as ``norm_init`` makes it for ``kind``."""
+    if kind == "rms":
+        return rms_norm(x, p, eps)
+    return layer_norm(x, p["scale"], p["bias"], eps)
 
 
 # ---------------------------------------------------------------------------

@@ -21,7 +21,7 @@ from . import blocks
 from . import mla as mla_mod
 from . import ssm as ssm_mod
 from .config import ModelConfig
-from .layers import lecun_normal, norm
+from .layers import lecun_normal, norm, norm_init
 
 PyTree = Any
 
@@ -47,7 +47,7 @@ class Model:
             "embed": (jax.random.normal(k_emb, (cfg.vocab_size, cfg.d_model))
                       * 0.02).astype(dt),
             "decoder": blocks.stack_init(k_stack, cfg, dt),
-            "final_norm": jnp.ones((cfg.d_model,), dt),
+            "final_norm": norm_init(cfg.d_model, cfg.norm_type, dt),
         }
         if not cfg.tie_embeddings:
             params["lm_head"] = lecun_normal(k_head,

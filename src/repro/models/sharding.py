@@ -33,7 +33,7 @@ _RULES: Tuple[Tuple[str, P], ...] = (
     (r"embed$",                      P("model", None)),
     (r"lm_head$",                    P(None, "model")),
     (r"frontend_proj$",              P(None, None)),
-    (r"final_norm$",                 P(None)),
+    (r"final_norm(/scale|/bias)?$",  P(None)),
     # attention (GQA)
     (r"attn/(q|k|v)/w$",             P(None, "model")),
     (r"attn/(q|k|v)/b$",             P("model")),
@@ -63,8 +63,8 @@ _RULES: Tuple[Tuple[str, P], ...] = (
     (r"ssm/conv_(w|b)$",             P()),            # tiny; replicated
     (r"ssm/gate_norm$",              P("model")),
     (r"ssm/w_out$",                  P("model", None)),
-    # norms
-    (r"ln\d$",                       P(None)),
+    # norms (layer norm: a scale and a shift)
+    (r"ln\d(/scale|/bias)?$",        P(None)),
 )
 
 
