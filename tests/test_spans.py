@@ -124,6 +124,14 @@ def test_server_run_records_each_layer_per_segment():
                    and run.t0 <= r.t0 and r.t1 <= run.t1]
         assert len(batches) == K
         assert {r.parent for r in batches} == {"server.batches"}
+        gathers = [r for r in rec.spans if r.name == "data.gather"
+                   and run.t0 <= r.t0 and r.t1 <= run.t1]
+        assert len(gathers) == K
+        assert {r.parent for r in gathers} == {"data.batch"}
+    # the training set goes to the device once, in the first draw
+    uploads = [r for r in rec.spans if r.name == "data.upload"]
+    assert [r.parent for r in uploads] == ["data.batch"]
+    assert runs[0].t0 <= uploads[0].t0 and uploads[0].t1 <= runs[0].t1
     assert [r.parent for r in rec.spans if r.name == "plan.build"] == [
         None, None]
 

@@ -79,6 +79,73 @@ def test_federated_batcher_shapes():
     assert y.shape == (6, 4, 8)
 
 
+def _host_gather(ds, parts, T, B, rng):
+    """The batch draw as a host gather: the oracle for the device one."""
+    xs = np.empty((len(parts), T, B) + ds.x.shape[1:], dtype=ds.x.dtype)
+    ys = np.empty((len(parts), T, B), dtype=ds.y.dtype)
+    for i, part in enumerate(parts):
+        idx = rng.choice(part, size=(T, B), replace=True)
+        xs[i] = ds.x[idx]
+        ys[i] = ds.y[idx]
+    return jnp.asarray(xs), jnp.asarray(ys)
+
+
+def _batcher_data(kind):
+    rng = np.random.default_rng(11)
+    if kind == "flat":
+        ds = Dataset(rng.standard_normal((500, 20)).astype(np.float32),
+                     rng.integers(0, 5, 500))
+        return ds, iid_partition(ds, 5)
+    if kind == "lane_aligned":              # 128 features: rows unpadded
+        ds = Dataset(rng.standard_normal((300, 16, 8)).astype(np.float32),
+                     rng.integers(0, 3, 300))
+    else:
+        ds = make_classification(n_samples=600, seed=0)
+    if kind == "image":
+        return ds, label_sorted_partition(ds, 6, 2)
+    n = len(ds)
+    return ds, [np.arange(0, 3), np.arange(3, 40), np.arange(40, n, 7),
+                np.array([n - 1])]
+
+
+@pytest.mark.parametrize("kind", ["image", "flat", "unequal",
+                                  "lane_aligned"])
+def test_federated_batcher_matches_host_gather_bitwise(kind):
+    ds, parts = _batcher_data(kind)
+    T, B = 3, 5
+    batcher = FederatedBatcher(ds, parts, T=T, batch_size=B)
+    rng, rng_ref = np.random.default_rng(5), np.random.default_rng(5)
+    for t in range(3):
+        x, y = batcher(rng, t)
+        x_ref, y_ref = _host_gather(ds, parts, T, B, rng_ref)
+        for got, want in ((x, x_ref), (y, y_ref)):
+            assert got.shape == want.shape and got.dtype == want.dtype
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert rng.bit_generator.state == rng_ref.bit_generator.state
+
+
+def test_federated_batcher_sends_only_indices_after_first_call(capfd):
+    ds = make_classification(n_samples=600, seed=0)
+    batcher = FederatedBatcher(ds, label_sorted_partition(ds, 6, 2), T=4,
+                               batch_size=8)
+    rng = np.random.default_rng(0)
+    jax.block_until_ready(batcher(rng, 0))
+    capfd.readouterr()
+    # logs every host-to-device transfer, explicit or implicit
+    with jax.transfer_guard_host_to_device("log_explicit"):
+        jax.block_until_ready(batcher(rng, 1))
+    sent = re.findall(r"host-to-device transfer: aval=ShapedArray\((.*?)\)",
+                      capfd.readouterr().err)
+    assert sent == ["int32[6,4,8]"]
+
+
+def test_federated_batcher_rejects_indices_outside_the_dataset():
+    ds = make_classification(n_samples=50, seed=0)
+    with pytest.raises(ValueError, match="outside"):
+        FederatedBatcher(ds, [np.arange(10), np.array([3, 50])], T=1,
+                         batch_size=2)
+
+
 def test_token_stream_and_lm_batches():
     toks = make_token_stream(n_tokens=4096, vocab=97, seed=0)
     assert toks.min() >= 0 and toks.max() < 97

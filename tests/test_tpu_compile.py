@@ -3,7 +3,8 @@
 The chip is described, not attached: the TPU compiler lowers each kernel
 at the paper CNN's payload width (n=70 clients padded to 72, ~1.66M fp32
 params padded to the 2048-column chunk) and must emit a Mosaic
-``tpu_custom_call``.  Nothing runs, so these say nothing about results or
+``tpu_custom_call``.  The batcher's device gather compiles at the same
+cell's training set.  Nothing runs, so these say nothing about results or
 time; the interpret-mode suites pin the results.
 """
 
@@ -13,9 +14,11 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.data.loader import _gather, _lane_rows
 from repro.fl.packing import QuantSpec
 from repro.kernels.flash_attention.flash_attention import (
     flash_attention_pallas)
@@ -168,3 +171,16 @@ def test_each_kernel_is_named_after_its_op(sds, op):
     }
     kernel, *args = kernels[op]
     assert _named(kernel, *args) == op
+
+
+def test_batch_gather_reads_training_rows_in_place(sds):
+    """At the CNN cell's 60,000 MNIST-shaped samples, the lane-padded rows
+    keep the row-major layout, so the gather copies no part of the
+    training set but the rows it draws."""
+    width = _lane_rows(np.zeros((1, 28, 28, 1), np.float32)).shape[1]
+    rows = sds((60_000, width))
+    compiled = _gather.lower(rows, sds((60_000,), jnp.int32),
+                             sds((70, 5, 32), jnp.int32),
+                             shape=(28, 28, 1)).compile()
+    assert f"f32[60000,{width}]{{1,0:T(8,128)}}" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 60_000 * 784
