@@ -54,9 +54,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.fl import packing
-from repro.kernels.mixing.ops import (aggregate, aggregate_grouped_q, mix,
-                                      mix_aggregate, sparse_aggregate,
-                                      sparse_mix)
+from repro.kernels.mixing.ops import (aggregate_grouped,
+                                      mix_aggregate_grouped)
 from repro.kernels.mixing.ref import mix_ref
 
 __all__ = ["run", "traffic_model", "mesh_traffic_model",
@@ -204,7 +203,8 @@ def quant_payload_rows(quiet: bool = False):
         tau = jnp.ones(n, jnp.float32)
         m = jnp.float32(n)
         dq = packing.dequantize_packed(stored, scales, qspec)
-        got = aggregate_grouped_q(A, tau, m, stored, scales, quant=quant)
+        got = aggregate_grouped(A, tau, m, stored, scales=scales,
+                                quant=quant)
         for g, d in zip(got, dq):
             ref = np.einsum("i,ip->p", np.asarray(tau),
                             np.asarray(d, np.float32)) / float(n)
@@ -286,12 +286,15 @@ def sparse_vs_dense_rows(quiet: bool = False):
         tau = jnp.asarray(rng2.integers(0, 2, n), jnp.float32)
         m = jnp.float32(max(1.0, float(tau.sum())))
 
-        np.testing.assert_allclose(np.asarray(sparse_mix(idx, w, X)),
-                                   np.asarray(mix(A, X)),
+        (sparse_mixed,), (sparse_agg,) = mix_aggregate_grouped(
+            (idx, w), tau, m, (X,))
+        (dense_mixed,), (dense_agg,) = mix_aggregate_grouped(A, tau, m, (X,))
+        np.testing.assert_allclose(np.asarray(sparse_mixed),
+                                   np.asarray(dense_mixed),
                                    rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(
-            np.asarray(sparse_aggregate(idx, w, tau, m, X)),
-            np.asarray(aggregate(A, tau, m, X)), rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(sparse_agg),
+                                   np.asarray(dense_agg),
+                                   rtol=1e-4, atol=1e-4)
 
         d_max = int(idx_np.shape[1])
         bytes_dense = n * n * 4
@@ -329,15 +332,16 @@ def run(quiet: bool = False):
         ref_mixed = mix_ref(A, X)
         ref_agg = np.einsum("i,ip->p", np.asarray(tau, np.float32),
                             np.asarray(ref_mixed, np.float32)) / float(m)
-        got_mixed, got_agg = mix_aggregate(A, tau, m, X)
+        (got_mixed,), (got_agg,) = mix_aggregate_grouped(A, tau, m, (X,))
         atol = 5e-2 if dtype == jnp.bfloat16 else 1e-4
         np.testing.assert_allclose(np.asarray(got_mixed, np.float32),
                                    np.asarray(ref_mixed, np.float32),
                                    rtol=atol, atol=atol)
         np.testing.assert_allclose(np.asarray(got_agg), ref_agg,
                                    rtol=atol, atol=atol)
-        np.testing.assert_allclose(np.asarray(aggregate(A, tau, m, X)),
-                                   ref_agg, rtol=atol, atol=atol)
+        (agg_only,) = aggregate_grouped(A, tau, m, (X,))
+        np.testing.assert_allclose(np.asarray(agg_only), ref_agg,
+                                   rtol=atol, atol=atol)
 
         model = traffic_model(n, p, np.dtype(dtype).itemsize)
         # cross-worker model: 8 workers (the CPU test mesh) moving this

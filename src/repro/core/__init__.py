@@ -26,9 +26,9 @@ from .graphs import (ClusterGraph, D2DNetwork, DegreeStats,
                      ensure_positive_out_degree, k_regular_digraph)
 from .sparse import SparseA, SparseAseq, ell_from_dense
 from .metrics import CommLedger, count_d2d_transmissions
-from .rounds import (MIXING_BACKENDS, client_deltas, fused_mix_update,
-                     global_update, local_sgd, make_round_fn,
-                     make_scanned_rounds, mix_deltas)
+from .rounds import (MIXING_BACKENDS, client_deltas, global_update,
+                     local_sgd, make_round_fn, make_scanned_rounds,
+                     mix_deltas)
 from .sampling import min_clients, sample_clients
 from .server import FederatedServer, History, RoundRecord, ServerConfig
 from .theory import TheoryConstants, eta_schedule, gap_bound, t1_threshold

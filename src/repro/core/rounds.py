@@ -15,38 +15,41 @@ Everything topology- and sampling-dependent (``A``, ``tau``, ``m``, ``eta``)
 enters as *runtime arrays*, so one compiled round serves all rounds of all
 three algorithms (Alg. 1, FedAvg via ``A = I``, COLREL via fixed ``m``).
 
-Steps 2+3 are the memory-bound hot path and come in three interchangeable
+Steps 2+3 are the memory-bound hot path and come in interchangeable
 backends (``make_round_fn(..., mixing_backend=...)``):
 
-  'einsum' -- leaf-wise jnp (``mix_deltas`` + ``global_update``); the
-              reference oracle.  fp32 accumulation regardless of delta
-              dtype, matching the Pallas kernels.
-  'pallas' -- leaf-wise Pallas mixing kernel (one launch per leaf) +
-              einsum aggregate.
-  'fused'  -- packed one-pass path: the delta pytree is flattened into
-              per-dtype lane-aligned (n, P_pad_g) buffers
-              (``repro.fl.packing``) and the fused kernel streams each
-              ONCE at its native dtype, emitting both the mixed deltas
-              (eq. 3) and the tau-weighted aggregate rows (eq. 4) in one
-              launch per dtype group (one per round for homogeneous
-              trees; mixed bf16/fp32 trees never promote to fp32 on the
-              wire).
+  'einsum'    -- leaf-wise jnp (``mix_deltas`` + ``global_update``); the
+                 reference oracle.  fp32 accumulation regardless of delta
+                 dtype, matching the Pallas kernels.
+  'fused'     -- packed one-pass path: the delta pytree is flattened into
+                 per-dtype lane-aligned (n, P_pad_g) buffers
+                 (``repro.fl.packing``) and the fused kernel streams each
+                 ONCE at its native dtype, emitting both the mixed deltas
+                 (eq. 3) and the tau-weighted aggregate rows (eq. 4) in
+                 one launch per dtype group (mixed bf16/fp32 trees never
+                 promote to fp32 on the wire).
   'aggregate' -- aggregate-only fast path: same packed buffers, but the
-              kernel computes only ``((tau^T A)/m) @ X_g`` -- the mixed
-              deltas are never materialized and the round returns ``None``
-              in their place (~3x less payload traffic than two-pass; see
-              BENCH_mixing.json).  The ``FederatedServer`` selects this
-              automatically when nothing records per-client mixed deltas.
-  'sparse' / 'sparse_aggregate' -- the ELL (neighbor-list) backends: ``A``
-              arrives as the 2-tuple ``(idx, w)`` of (n, d_max) arrays
-              (``repro.core.sparse.SparseA.ell()``) instead of an (n, n)
-              matrix, and eq. 3 runs as d_max row gathers while the eq.-4
-              combine row is a segment-sum over the same entries
-              (``kernels.mixing.ops``).  O(n d_max p) work and O(n
-              d_max) topology storage -- the only backends that scale n
-              past the dense O(n^2) wall.  allclose (not bitwise) to
-              'einsum': fp32 accumulation both sides, reduction order
-              differs.
+                 kernel computes only ``((tau^T A)/m) @ X_g`` -- the mixed
+                 deltas are never materialized and the round returns
+                 ``None`` in their place (~3x less payload traffic than
+                 two-pass; see BENCH_mixing.json).  The ``FederatedServer``
+                 selects this automatically when nothing records
+                 per-client mixed deltas.
+  'sparse' / 'sparse_aggregate' -- the same two legs with ``A`` as the
+                 ELL (neighbor-list) 2-tuple ``(idx, w)`` of (n, d_max)
+                 arrays (``repro.core.sparse.SparseA.ell()``) instead of
+                 an (n, n) matrix: eq. 3 runs as d_max row gathers and the
+                 eq.-4 combine row is a segment-sum over the same entries.
+                 O(n d_max p) work and O(n d_max) topology storage -- the
+                 only backends that scale n past the dense O(n^2) wall.
+                 allclose (not bitwise) to 'einsum': fp32 accumulation
+                 both sides, reduction order differs.
+
+Every backend but 'einsum' goes through ``kernels.mixing.ops``
+(``mix_aggregate_grouped`` / ``aggregate_grouped``), which tells the two
+forms of ``A`` apart by type.  Every backend also takes a quantized
+payload (``quant``): the kernels dequantize in VMEM, the einsum oracle
+on the host side of the wire.
 
 ``make_scanned_rounds`` wraps the round in ``jax.lax.scan`` over stacked
 ``(A_t, tau_t, m_t, eta_t[, active_t])`` sequences so a K-round
@@ -68,7 +71,7 @@ The multi-device shard_map implementation with the same semantics lives in
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -78,26 +81,17 @@ __all__ = [
     "client_deltas",
     "mix_deltas",
     "global_update",
-    "fused_mix_update",
     "mask_clients",
     "make_round_fn",
     "make_scanned_rounds",
     "MIXING_BACKENDS",
-    "QUANT_BACKENDS",
 ]
 
 PyTree = Any
 LossFn = Callable[[PyTree, PyTree], jnp.ndarray]  # (params, batch) -> scalar
 
-MIXING_BACKENDS = ("einsum", "pallas", "fused", "aggregate", "sparse",
+MIXING_BACKENDS = ("einsum", "fused", "aggregate", "sparse",
                    "sparse_aggregate")
-
-# backends that accept quantized payload groups: every packed one-pass
-# path (dequant fused into the kernels) plus the einsum oracle (which
-# mixes the dequantized fp32 buffers directly).  The leaf-wise 'pallas'
-# backend has no packed buffers to attach scales to.
-QUANT_BACKENDS = ("einsum", "fused", "aggregate", "sparse",
-                  "sparse_aggregate")
 
 
 def local_sgd(loss_fn: LossFn, params: PyTree, batches: PyTree,
@@ -181,106 +175,6 @@ def global_update(global_params: PyTree, mixed: PyTree, tau: jnp.ndarray,
         return jax.tree.map(upd, global_params, mixed)
 
 
-def fused_mix_update(global_params: PyTree, deltas: PyTree, A: jnp.ndarray,
-                     tau: jnp.ndarray, m: jnp.ndarray, *, chunk: int = 2048,
-                     interpret: Optional[bool] = None,
-                     active: Optional[jnp.ndarray] = None
-                     ) -> Tuple[PyTree, PyTree]:
-    """One-pass eq. 3 + eq. 4 over the packed delta buffers.
-
-    Packs the delta pytree into per-dtype (n, P_pad_g) buffers, launches
-    the fused Pallas kernel once per dtype group (streaming each group's
-    payload through VMEM a single time at its native dtype), and returns
-    ``(new_global_params, mixed_deltas)``.  With a straggler mask the
-    packed buffers are masked before the launch so the *mixed* output
-    also reflects the drop (one multiply per group buffer).
-    """
-    # deferred: repro.fl lazily imports back into repro.core at package init
-    from repro.fl import packing
-    from repro.kernels.mixing.ops import mix_aggregate_grouped
-
-    spec = packing.pack_spec(deltas)
-    bufs = packing.pack(deltas, spec)
-    if active is not None:
-        bufs = tuple(mask_clients(list(bufs), active))
-    mixed_bufs, agg_rows = mix_aggregate_grouped(A, tau, m, bufs,
-                                                 chunk=chunk,
-                                                 interpret=interpret,
-                                                 active=active)
-    mixed = packing.unpack(mixed_bufs, spec)
-    new_global = packing.apply_aggregate_row(global_params, agg_rows, spec)
-    return new_global, mixed
-
-
-def _mix_and_update(global_params, deltas, A, tau, m, *, mixing_backend,
-                    chunk, interpret, active=None):
-    if mixing_backend in ("einsum", "pallas"):
-        # materializing backends: a dropped client's delta is zeroed
-        # before eq. 3 and its upload removed from the eq.-4 sum.
-        if active is not None:
-            deltas = mask_clients(deltas, active)
-            tau = tau * active
-        if mixing_backend == "einsum":
-            mixed = mix_deltas(A, deltas)
-        else:
-            from repro.kernels.mixing.ops import mix_pytree
-            mixed = mix_pytree(A, deltas, chunk=chunk, interpret=interpret)
-        return global_update(global_params, mixed, tau, m), mixed
-    if mixing_backend == "fused":
-        return fused_mix_update(global_params, deltas, A, tau, m,
-                                chunk=chunk, interpret=interpret,
-                                active=active)
-    if mixing_backend == "aggregate":
-        from repro.fl import packing
-        from repro.kernels.mixing.ops import aggregate_grouped
-
-        # one-pass path: the mask folds into the combine row
-        # (combine_weights) -- the payload itself is never touched.
-        spec = packing.pack_spec(deltas)
-        bufs = packing.pack(deltas, spec)
-        agg_rows = aggregate_grouped(A, tau, m, bufs, chunk=chunk,
-                                     interpret=interpret, active=active)
-        return packing.apply_aggregate_row(global_params, agg_rows,
-                                           spec), None
-    if mixing_backend in ("sparse", "sparse_aggregate"):
-        from repro.fl import packing
-        from repro.kernels.mixing.ops import (sparse_aggregate_grouped,
-                                              sparse_mix_aggregate_grouped)
-
-        idx, w = A      # ELL pair (n, d_max), never an (n, n) matrix
-        spec = packing.pack_spec(deltas)
-        bufs = packing.pack(deltas, spec)
-        if mixing_backend == "sparse_aggregate":
-            agg_rows = sparse_aggregate_grouped(idx, w, tau, m, bufs,
-                                                chunk=chunk,
-                                                interpret=interpret,
-                                                active=active)
-            return packing.apply_aggregate_row(global_params, agg_rows,
-                                               spec), None
-        if active is not None:
-            bufs = tuple(mask_clients(list(bufs), active))
-        mixed_bufs, agg_rows = sparse_mix_aggregate_grouped(
-            idx, w, tau, m, bufs, chunk=chunk, interpret=interpret,
-            active=active)
-        mixed = packing.unpack(mixed_bufs, spec)
-        return packing.apply_aggregate_row(global_params, agg_rows,
-                                           spec), mixed
-    raise ValueError(
-        f"mixing_backend must be one of {MIXING_BACKENDS}, "
-        f"got {mixing_backend!r}")
-
-
-def _check_quant_chunk_arg(quant, chunk: int) -> None:
-    """Fail fast at build time: every Pallas payload tile must cover
-    whole scale blocks (mirrors ``kernels.mixing.ops._check_quant_chunk``
-    without importing the kernel package at call-graph build)."""
-    if chunk % quant.block:
-        raise ValueError(
-            f"chunk ({chunk}) must be a multiple of quant.block "
-            f"({quant.block}) so every payload tile covers whole scale "
-            "blocks")
-
-
 def _quantize_deltas(deltas, *, quant, qstate, shards: int = 1):
     """Client-side quantizer step shared by every quant backend: pack the
     delta tree, quantize ``x + residual`` under ``quant``, and advance the
@@ -303,29 +197,44 @@ def _quantize_deltas(deltas, *, quant, qstate, shards: int = 1):
     return spec, stored, scales, new_qstate
 
 
-def _mix_and_update_quant(global_params, deltas, A, tau, m, *,
-                          mixing_backend, chunk, interpret, active, quant,
-                          qstate):
-    """Quantized eq. 3 + eq. 4: the deltas cross the wire as stored
-    containers + per-block scales and every backend consumes that wire
-    format directly (dequant fused into the kernels; the einsum oracle
-    dequantizes explicitly).  Returns ``(new_global, mixed, new_qstate)``.
+def _mix_and_update(global_params, deltas, A, tau, m, *, backend, chunk,
+                    interpret, active, quant, qstate):
+    """Eq. 3 + eq. 4 on one backend.  Returns ``(new_global, mixed,
+    new_qstate)``; ``mixed`` is None on the aggregate-only backends and
+    ``new_qstate`` is ``qstate`` as given when ``quant`` is None.
 
-    Straggler masks act on the *wire*: a dropped client's payload is
-    zeroed by masking its scale rows (mixed leg) and its upload folds out
-    of the combine row (aggregate leg).  The client-side quantizer state
-    still advances for dropped clients -- quantization happens before the
-    network, the drop on it.
+    With ``quant`` the deltas cross the wire as stored containers +
+    per-block scales: the kernel backends consume that wire format
+    directly and the einsum oracle dequantizes it first.  The client-side
+    quantizer state still advances for dropped clients -- quantization
+    happens before the network, the drop on it.
+
+    Straggler masks: the materializing legs zero a dropped client's
+    payload rows (its scale rows when quantized) before eq. 3 and remove
+    its upload from eq. 4; the aggregate-only legs fold the mask into
+    the combine row and never touch the payload.
     """
     from repro.fl import packing
+    from repro.kernels.mixing import ops
 
-    spec, stored, scales, new_qstate = _quantize_deltas(
-        deltas, quant=quant, qstate=qstate)
+    if backend == "einsum" and quant is None:
+        if active is not None:
+            deltas = mask_clients(deltas, active)
+            tau = tau * active
+        mixed = mix_deltas(A, deltas)
+        return global_update(global_params, mixed, tau, m), mixed, qstate
 
-    if mixing_backend == "einsum":
+    if quant is None:
+        spec = packing.pack_spec(deltas)
+        bufs, scales = packing.pack(deltas, spec), None
+    else:
+        spec, bufs, scales, qstate = _quantize_deltas(
+            deltas, quant=quant, qstate=qstate)
+
+    if backend == "einsum":
         # reference oracle: mix the dequantized fp32 buffers with the
         # same mask recipe as the unquantized einsum branch.
-        dq = packing.dequantize_packed(stored, scales, spec)
+        dq = packing.dequantize_packed(bufs, scales, spec)
         if active is not None:
             dq = tuple(mask_clients(list(dq), active))
             tau = tau * active
@@ -339,51 +248,23 @@ def _mix_and_update_quant(global_params, deltas, A, tau, m, *,
                        preferred_element_type=jnp.float32) / m
             for mb in mixed_bufs)
         return (packing.apply_aggregate_row(global_params, agg_rows, spec),
-                packing.unpack(mixed_bufs, spec), new_qstate)
+                packing.unpack(mixed_bufs, spec), qstate)
 
-    if mixing_backend in ("fused", "aggregate"):
-        from repro.kernels.mixing.ops import (aggregate_grouped_q,
-                                              mix_aggregate_grouped_q)
-
-        if mixing_backend == "aggregate":
-            agg_rows = aggregate_grouped_q(A, tau, m, stored, scales,
-                                           quant=quant, chunk=chunk,
-                                           interpret=interpret,
-                                           active=active)
-            return (packing.apply_aggregate_row(global_params, agg_rows,
-                                                spec), None, new_qstate)
-        if active is not None:
-            # mask the mixed leg on the scales -- one multiply on the
-            # tiny side buffer, the payload is never touched.
-            scales = tuple(mask_clients(list(scales), active))
-        mixed_bufs, agg_rows = mix_aggregate_grouped_q(
-            A, tau, m, stored, scales, quant=quant, chunk=chunk,
-            interpret=interpret, active=active)
+    kw = dict(quant=quant, chunk=chunk, interpret=interpret, active=active)
+    if backend in ("aggregate", "sparse_aggregate"):
+        agg_rows = ops.aggregate_grouped(A, tau, m, bufs, scales=scales,
+                                         **kw)
         return (packing.apply_aggregate_row(global_params, agg_rows, spec),
-                packing.unpack(mixed_bufs, spec), new_qstate)
-
-    if mixing_backend in ("sparse", "sparse_aggregate"):
-        from repro.kernels.mixing.ops import (
-            sparse_aggregate_grouped_q, sparse_mix_aggregate_grouped_q)
-
-        idx, w = A      # ELL pair (n, d_max), never an (n, n) matrix
-        if mixing_backend == "sparse_aggregate":
-            agg_rows = sparse_aggregate_grouped_q(
-                idx, w, tau, m, stored, scales, quant=quant, chunk=chunk,
-                interpret=interpret, active=active)
-            return (packing.apply_aggregate_row(global_params, agg_rows,
-                                                spec), None, new_qstate)
-        if active is not None:
+                None, qstate)
+    if active is not None:
+        if quant is None:
+            bufs = tuple(mask_clients(list(bufs), active))
+        else:
             scales = tuple(mask_clients(list(scales), active))
-        mixed_bufs, agg_rows = sparse_mix_aggregate_grouped_q(
-            idx, w, tau, m, stored, scales, quant=quant, chunk=chunk,
-            interpret=interpret, active=active)
-        return (packing.apply_aggregate_row(global_params, agg_rows, spec),
-                packing.unpack(mixed_bufs, spec), new_qstate)
-
-    raise ValueError(
-        f"quantized rounds support mixing_backend in {QUANT_BACKENDS}, "
-        f"got {mixing_backend!r}")
+    mixed_bufs, agg_rows = ops.mix_aggregate_grouped(A, tau, m, bufs,
+                                                     scales=scales, **kw)
+    return (packing.apply_aggregate_row(global_params, agg_rows, spec),
+            packing.unpack(mixed_bufs, spec), qstate)
 
 
 def make_round_fn(loss_fn: LossFn, jit: bool = True,
@@ -402,65 +283,48 @@ def make_round_fn(loss_fn: LossFn, jit: bool = True,
         effective sampled-and-active count (module docstring)
     Returns ``(new_global_params, mixed_deltas)`` -- the mixed deltas are
     exposed for testing and communication accounting, except under the
-    'aggregate' backend, which never materializes them and returns ``None``
-    in their place.
+    aggregate-only backends ('aggregate', 'sparse_aggregate'), which never
+    materialize them and return ``None`` in their place.
 
     ``mixing_backend`` selects the eq. 3 + eq. 4 implementation (module
     docstring); ``chunk``/``interpret`` configure the Pallas backends and
     are ignored by 'einsum'.  ``interpret=None`` (default) resolves per
     platform -- compiled on TPU, interpreter elsewhere
-    (``repro.kernels.mixing.ops.default_interpret``).
+    (``repro.kernels.dispatch.default_interpret``).
 
     ``quant`` (a ``repro.fl.packing.QuantSpec``, default None) switches
     the round to quantized payload groups: the signature grows a trailing
     ``qstate`` argument (``packing.init_quant_state``) and the round
-    returns ``(new_global_params, mixed_deltas, new_qstate)``.  Only
-    ``QUANT_BACKENDS`` support it; with ``quant=None`` nothing about the
-    unquantized path changes.
+    returns ``(new_global_params, mixed_deltas, new_qstate)``; ``chunk``
+    must then be a multiple of ``quant.block``.  With ``quant=None``
+    nothing about the unquantized path changes.
     """
     if mixing_backend not in MIXING_BACKENDS:
         raise ValueError(
             f"mixing_backend must be one of {MIXING_BACKENDS}, "
             f"got {mixing_backend!r}")
     if quant is not None:
-        if mixing_backend not in QUANT_BACKENDS:
-            raise ValueError(
-                f"quantized rounds support mixing_backend in "
-                f"{QUANT_BACKENDS}, got {mixing_backend!r}")
-        _check_quant_chunk_arg(quant, chunk)
-
-        def round_fn_q(global_params: PyTree, client_batches: PyTree,
-                       A: jnp.ndarray, tau: jnp.ndarray, m: jnp.ndarray,
-                       eta: jnp.ndarray,
-                       active: Optional[jnp.ndarray] = None,
-                       qstate=None) -> Tuple[PyTree, PyTree, Any]:
-            if qstate is None:
-                raise ValueError(
-                    "quantized round_fn needs the quantizer state: build "
-                    "it with packing.init_quant_state(spec, n) and thread "
-                    "the returned new_qstate into the next round")
-            deltas = client_deltas(loss_fn, global_params, client_batches,
-                                   eta)
-            with jax.named_scope("mix"):
-                return _mix_and_update_quant(
-                    global_params, deltas, A, tau, m,
-                    mixing_backend=mixing_backend, chunk=chunk,
-                    interpret=interpret, active=active, quant=quant,
-                    qstate=qstate)
-
-        return jax.jit(round_fn_q) if jit else round_fn_q
+        from repro.kernels.mixing.ops import _check_quant_chunk
+        _check_quant_chunk(quant, chunk)
 
     def round_fn(global_params: PyTree, client_batches: PyTree,
                  A: jnp.ndarray, tau: jnp.ndarray, m: jnp.ndarray,
-                 eta: jnp.ndarray,
-                 active: Optional[jnp.ndarray] = None
-                 ) -> Tuple[PyTree, PyTree]:
+                 eta: jnp.ndarray, active: Optional[jnp.ndarray] = None,
+                 qstate=None):
+        if quant is not None and qstate is None:
+            raise ValueError(
+                "quantized round_fn needs the quantizer state: build "
+                "it with packing.init_quant_state(spec, n) and thread "
+                "the returned new_qstate into the next round")
         deltas = client_deltas(loss_fn, global_params, client_batches, eta)
         with jax.named_scope("mix"):
-            return _mix_and_update(global_params, deltas, A, tau, m,
-                                   mixing_backend=mixing_backend,
-                                   chunk=chunk, interpret=interpret,
-                                   active=active)
+            new_global, mixed, qstate = _mix_and_update(
+                global_params, deltas, A, tau, m, backend=mixing_backend,
+                chunk=chunk, interpret=interpret, active=active,
+                quant=quant, qstate=qstate)
+        if quant is None:
+            return new_global, mixed
+        return new_global, mixed, qstate
 
     return jax.jit(round_fn) if jit else round_fn
 
@@ -501,45 +365,25 @@ def make_scanned_rounds(loss_fn: LossFn, K: int, jit: bool = True,
                              mixing_backend=mixing_backend, chunk=chunk,
                              interpret=interpret, quant=quant)
 
-    if quant is not None:
-        def scanned_q(global_params: PyTree, client_batches_seq: PyTree,
-                      A_seq: jnp.ndarray, tau_seq: jnp.ndarray,
-                      m_seq: jnp.ndarray, eta_seq: jnp.ndarray,
-                      active_seq: Optional[jnp.ndarray] = None,
-                      qstate=None) -> Tuple[PyTree, PyTree, Any]:
-            def body(carry, xs):
-                params, qs = carry
-                batches, A, tau, m, eta = xs[:5]
-                active = xs[5] if active_seq is not None else None
-                new_params, _, new_qs = round_fn(params, batches, A, tau,
-                                                 m, eta, active, qs)
-                return (new_params, new_qs), new_params
-
-            xs = (client_batches_seq, A_seq, tau_seq, m_seq, eta_seq)
-            if active_seq is not None:
-                xs = xs + (active_seq,)
-            (final, final_qstate), params_seq = jax.lax.scan(
-                body, (global_params, qstate), xs, length=K)
-            return final, params_seq, final_qstate
-
-        return jax.jit(scanned_q) if jit else scanned_q
-
     def scanned(global_params: PyTree, client_batches_seq: PyTree,
                 A_seq: jnp.ndarray, tau_seq: jnp.ndarray,
                 m_seq: jnp.ndarray, eta_seq: jnp.ndarray,
-                active_seq: Optional[jnp.ndarray] = None
-                ) -> Tuple[PyTree, PyTree]:
-        def body(params, xs):
+                active_seq: Optional[jnp.ndarray] = None, qstate=None):
+        def body(carry, xs):
+            params, qs = carry
             batches, A, tau, m, eta = xs[:5]
             active = xs[5] if active_seq is not None else None
-            new_params, _ = round_fn(params, batches, A, tau, m, eta,
-                                     active)
-            return new_params, new_params
+            out = round_fn(params, batches, A, tau, m, eta, active, qs)
+            new_qs = out[2] if quant is not None else qs
+            return (out[0], new_qs), out[0]
 
         xs = (client_batches_seq, A_seq, tau_seq, m_seq, eta_seq)
         if active_seq is not None:
             xs = xs + (active_seq,)
-        final, params_seq = jax.lax.scan(body, global_params, xs, length=K)
-        return final, params_seq
+        (final, final_qstate), params_seq = jax.lax.scan(
+            body, (global_params, qstate), xs, length=K)
+        if quant is None:
+            return final, params_seq
+        return final, params_seq, final_qstate
 
     return jax.jit(scanned) if jit else scanned

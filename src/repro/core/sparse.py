@@ -28,7 +28,7 @@ module holds the representations that remove the O(n^2) wall:
 
 The eq.-4 D2S combine row ``(tau^T A) / m`` never needs the dense matrix
 either: it is a segment-sum over the same edge list
-(``repro.kernels.mixing.ops.combine_weights_ell``).
+(``repro.kernels.mixing.ops.combine_weights`` on the ELL pair).
 """
 
 from __future__ import annotations

@@ -23,17 +23,20 @@ Backend selection (one matrix, one place)::
 
     runtime      backends                       record_mixed     scan
     -----------  -----------------------------  ---------------  ----
-    LocalEngine  einsum | pallas | fused        False upgrades    yes
-                 | aggregate | sparse           pallas/fused ->
-                 | sparse_aggregate             'aggregate' and
+    LocalEngine  einsum | fused | aggregate     False upgrades    yes
+                 | sparse | sparse_aggregate    fused ->
+                                                'aggregate' and
                                                 sparse ->
                                                 'sparse_aggregate'
     MeshEngine   ring | gather | einsum         unsupported       yes
                  | fused | fused_rs
-    StreamEngine einsum | pallas | fused        unsupported       no
-                 | aggregate (pallas/fused      (mixed deltas     (event
-                 always -> 'aggregate';         never kept)       loop)
-                 sparse* rejected)
+    StreamEngine einsum | fused | aggregate     unsupported       no
+                 (fused always -> 'aggregate';  (mixed deltas     (event
+                 sparse* rejected)              never kept)       loop)
+
+Every local backend takes a quantized payload; on the mesh only the
+one-pass 'fused' / 'fused_rs' schedules do, and the stream runtime takes
+none.
 
 The sparse backends consume the plan's ``A_t`` column in ELL form
 (``repro.core.sparse``) -- a sparse plan never densifies on this path,
@@ -56,8 +59,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.metrics import CommLedger
-from repro.core.rounds import MIXING_BACKENDS, QUANT_BACKENDS, \
-    make_round_fn, make_scanned_rounds
+from repro.core.rounds import MIXING_BACKENDS, make_round_fn, \
+    make_scanned_rounds
 from repro.core.server import History, RoundRecord
 from repro.core.sparse import SparseAseq
 from repro.spans import span
@@ -91,8 +94,8 @@ class ExecutionConfig:
     ``quant`` (a ``repro.fl.packing.QuantSpec``) turns on quantized
     payload groups -- it overrides a plan-carried ``plan.quant``; either
     source is validated against the effective backend at execute time
-    (``QUANT_BACKENDS`` locally, 'fused'/'fused_rs' on the mesh; the
-    stream runtime rejects quantization).
+    (every local backend quantizes, the mesh only 'fused'/'fused_rs';
+    the stream runtime rejects quantization).
     """
     backend: str = "einsum"
     scan: bool = False
@@ -107,26 +110,13 @@ class ExecutionConfig:
     runtime: Any = None
 
 
-def _check_quant_backend(quant, backend: str, mesh: bool) -> None:
-    """One quant-support matrix: the packed one-pass paths locally
-    (``QUANT_BACKENDS``), the one-pass schedules on the mesh.  Validates
-    the *effective* backend, so e.g. 'fused' that upgraded to 'aggregate'
-    still quantizes while 'pallas' kept alive by record_mixed is
-    rejected (its leaf-wise kernels have no packed buffers to attach
-    scales to)."""
-    if quant is None:
-        return
-    if mesh:
-        if backend not in ("fused", "fused_rs"):
-            raise ValueError(
-                "quantized payloads on the mesh runtime require the "
-                f"one-pass 'fused' or 'fused_rs' schedules, got "
-                f"{backend!r}")
-        return
-    if backend not in QUANT_BACKENDS:
+def _check_mesh_quant(quant, backend: str) -> None:
+    """Quantized payloads on the mesh need a one-pass schedule (every
+    local backend quantizes)."""
+    if quant is not None and backend not in ("fused", "fused_rs"):
         raise ValueError(
-            f"quantized rounds support mixing_backend in "
-            f"{QUANT_BACKENDS}, got {backend!r}")
+            "quantized payloads on the mesh runtime require the "
+            f"one-pass 'fused' or 'fused_rs' schedules, got {backend!r}")
 
 
 def resolve_backend(cfg: ExecutionConfig) -> str:
@@ -170,7 +160,7 @@ def resolve_backend(cfg: ExecutionConfig) -> str:
                 "runtime: cohort closure slices dense A_t rows; use "
                 "LocalEngine (backend='sparse') or densify the plan")
         # stale cohorts always take the aggregate-only combine-row path
-        if cfg.backend in ("pallas", "fused"):
+        if cfg.backend == "fused":
             return "aggregate"
         return cfg.backend
     if cfg.mesh is not None:
@@ -182,7 +172,7 @@ def resolve_backend(cfg: ExecutionConfig) -> str:
             raise ValueError(
                 "record_mixed is not supported on the mesh runtime: "
                 "the mesh train step never returns mixed deltas")
-        _check_quant_backend(cfg.quant, cfg.backend, mesh=True)
+        _check_mesh_quant(cfg.quant, cfg.backend)
         return cfg.backend
     if cfg.backend not in MIXING_BACKENDS:
         raise ValueError(
@@ -196,13 +186,11 @@ def resolve_backend(cfg: ExecutionConfig) -> str:
     # History never records per-client mixed deltas, so unless the caller
     # explicitly keeps them, the kernel backends dispatch the
     # aggregate-only fast path (~3x less payload traffic).
-    effective = cfg.backend
-    if not cfg.record_mixed and cfg.backend in ("pallas", "fused"):
-        effective = "aggregate"
+    if not cfg.record_mixed and cfg.backend == "fused":
+        return "aggregate"
     if not cfg.record_mixed and cfg.backend == "sparse":
-        effective = "sparse_aggregate"
-    _check_quant_backend(cfg.quant, effective, mesh=False)
-    return effective
+        return "sparse_aggregate"
+    return cfg.backend
 
 
 class Engine(Protocol):
@@ -264,7 +252,8 @@ def _quant_setup(cfg: ExecutionConfig, plan: RoundPlan, params: PyTree,
     quant = cfg.quant if cfg.quant is not None else plan.quant
     if quant is None:
         return None, None
-    _check_quant_backend(quant, backend, mesh=mesh is not None)
+    if mesh is not None:
+        _check_mesh_quant(quant, backend)
     from . import packing
 
     shards = 1

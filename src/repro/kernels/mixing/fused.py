@@ -33,13 +33,13 @@ Two entry points:
     FedAvg (``A = I`` makes ``mixed`` redundant) and for server rounds
     that do not log per-client deltas.
 
-Shape contract matches ``mixing.mix_pallas``: callers (``ops.py``) pad
-``n`` to the float32 sublane multiple and ``p`` to a multiple of
-``chunk``; ``w`` arrives padded to ``(_SUBLANE, n_pad)`` with the real
-weights in row 0.  Validated in interpret mode on CPU against the
-composed ``mix_ref`` + eq.-4 oracle (tests/test_fused_mixing.py); the
-wrappers in ``ops.py`` select compiled lowering (``interpret=False``)
-automatically on TPU (``repro.kernels.dispatch.default_interpret``).
+Shape contract: callers (``ops.py``) pad ``n`` to the float32 sublane
+multiple and ``p`` to a multiple of ``chunk``; ``w`` arrives padded to
+``(_SUBLANE, n_pad)`` with the real weights in row 0.  Validated in
+interpret mode on CPU against the composed ``mix_ref`` + eq.-4 oracle
+(tests/test_fused_mixing.py); ``ops.py`` selects compiled lowering
+(``interpret=False``) automatically on TPU
+(``repro.kernels.dispatch.default_interpret``).
 """
 
 from __future__ import annotations
@@ -50,10 +50,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .mixing import f32_matmul
-
 __all__ = ["mix_aggregate_pallas", "aggregate_pallas", "dequant_tile",
-           "mix_aggregate_dequant_pallas", "aggregate_dequant_pallas"]
+           "mix_aggregate_dequant_pallas", "aggregate_dequant_pallas",
+           "f32_matmul"]
+
+
+def f32_matmul(a: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """``a @ x`` of fp32 operands with fp32 products and accumulation.
+    Mosaic's default precision rounds fp32 operands to bf16 (on a v5e
+    that put ~2e-3 relative error into the CNN aggregate); HIGHEST keeps
+    the kernels at the fp32 reference's accuracy."""
+    return jax.lax.dot_general(
+        a, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
 
 
 def _fused_kernel(a_ref, w_ref, x_ref, mixed_ref, agg_ref):

@@ -13,10 +13,22 @@ from repro.core import (D2DNetwork, FederatedServer, ServerConfig,
                         client_deltas, global_update, make_round_fn,
                         make_scanned_rounds, mix_deltas, network_matrix)
 from repro.fl import packing
-from repro.kernels.mixing.ops import aggregate, mix_aggregate
+from repro.kernels.mixing.ops import (aggregate_grouped,
+                                      mix_aggregate_grouped)
 from repro.kernels.mixing.ref import mix_ref
 
 jax.config.update("jax_enable_x64", False)
+
+
+def mix_aggregate(A, tau, m, X, **kw):
+    """One (n, p) buffer through the grouped entry point."""
+    (mixed,), (agg,) = mix_aggregate_grouped(A, tau, m, (X,), **kw)
+    return mixed, agg
+
+
+def aggregate(A, tau, m, X, **kw):
+    (agg,) = aggregate_grouped(A, tau, m, (X,), **kw)
+    return agg
 
 
 # ---------------------------------------------------------------------------
@@ -218,7 +230,7 @@ def _round_inputs(rng, n, p, T, B, K):
     return batches, As, taus, ms
 
 
-@pytest.mark.parametrize("backend", ["pallas", "fused"])
+@pytest.mark.parametrize("backend", ["fused"])
 def test_round_fn_backend_matches_einsum(backend):
     rng = np.random.default_rng(9)
     n, p, T, B, K = 6, 5, 3, 2, 3
@@ -322,7 +334,7 @@ def test_make_round_fn_rejects_unknown_backend():
 
 # ---------------------------------------------------------------------------
 # aggregate-only round variant (ROADMAP: server rounds that never record
-# per-client mixed deltas dispatch kernels.mixing.ops.aggregate)
+# per-client mixed deltas dispatch kernels.mixing.ops.aggregate_grouped)
 # ---------------------------------------------------------------------------
 
 def test_round_fn_aggregate_matches_einsum_and_returns_no_mixed():
@@ -344,7 +356,6 @@ def test_round_fn_aggregate_matches_einsum_and_returns_no_mixed():
 
 @pytest.mark.parametrize("requested,recorded,effective", [
     ("fused", False, "aggregate"),
-    ("pallas", False, "aggregate"),
     ("fused", True, "fused"),
     ("einsum", False, "einsum"),
 ])
@@ -494,9 +505,6 @@ def test_pack_grouped_round_trip_property(n_bf16, n_fp32, n, shards, seed):
 
 def test_grouped_kernel_launch_matches_leafwise_oracle():
     """One fused launch per dtype group == leaf-wise eq. 3 + eq. 4."""
-    from repro.kernels.mixing.ops import (aggregate_grouped,
-                                          mix_aggregate_grouped)
-
     rng = np.random.default_rng(141)
     n = 6
     tree = _mixed_tree(rng, n)

@@ -9,14 +9,22 @@ from hypothesis_compat import given, settings, strategies as st
 
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.mixing.ops import mix, mix_pytree
+from repro.fl import packing
+from repro.kernels.mixing.ops import mix_aggregate_grouped
 from repro.kernels.mixing.ref import mix_ref
 from repro.core import D2DNetwork, network_matrix
 
 
 # ---------------------------------------------------------------------------
-# Graph-mixing kernel
+# Graph-mixing kernel: the mixed leg of ``mix_aggregate_grouped``
 # ---------------------------------------------------------------------------
+
+def _mix(A, X, chunk=2048):
+    n = A.shape[0]
+    (mixed,), _ = mix_aggregate_grouped(A, jnp.ones(n), jnp.float32(n),
+                                        (X,), chunk=chunk)
+    return mixed
+
 
 @given(st.integers(2, 40), st.integers(1, 5000),
        st.sampled_from([jnp.float32, jnp.bfloat16]),
@@ -26,7 +34,7 @@ def test_mixing_kernel_matches_ref(n, p, dtype, seed):
     rng = np.random.default_rng(seed)
     A = jnp.asarray(rng.random((n, n)), jnp.float32)
     X = jnp.asarray(rng.standard_normal((n, p)), dtype)
-    got = mix(A, X, chunk=512)
+    got = _mix(A, X, chunk=512)
     want = mix_ref(A, X)
     assert got.dtype == X.dtype
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
@@ -42,7 +50,7 @@ def test_mixing_kernel_column_stochastic_preserves_sum():
     net = D2DNetwork(n=32, c=2, p_fail=0.15)
     A = jnp.asarray(network_matrix(net.sample(rng), 32), jnp.float32)
     X = jnp.asarray(rng.standard_normal((32, 4097)), jnp.float32)
-    out = mix(A, X)
+    out = _mix(A, X)
     np.testing.assert_allclose(np.asarray(out.sum(0)), np.asarray(X.sum(0)),
                                rtol=1e-4, atol=1e-4)
 
@@ -53,7 +61,10 @@ def test_mixing_pytree_matches_tree_einsum():
     A = jnp.asarray(rng.random((n, n)), jnp.float32)
     deltas = {"w": jnp.asarray(rng.standard_normal((n, 33, 7)), jnp.float32),
               "b": jnp.asarray(rng.standard_normal((n, 129)), jnp.float32)}
-    got = mix_pytree(A, deltas)
+    spec = packing.pack_spec(deltas)
+    (mixed,), _ = mix_aggregate_grouped(A, jnp.ones(n), jnp.float32(n),
+                                        packing.pack(deltas, spec))
+    got = packing.unpack((mixed,), spec)
     for key in deltas:
         flat = deltas[key].reshape(n, -1)
         want = mix_ref(A, flat).reshape(deltas[key].shape)

@@ -220,4 +220,4 @@ def test_server_mesh_requires_model_cfg_and_valid_mixing():
     with pytest.raises(ValueError, match="mesh mixing"):
         FederatedServer(net, None, {}, lambda r, t: None, scfg,
                         algorithm="fedavg", mesh=mesh,
-                        model_cfg=mesh_cfg, mixing_backend="pallas")
+                        model_cfg=mesh_cfg, mixing_backend="aggregate")

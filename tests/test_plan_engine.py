@@ -19,6 +19,7 @@ from repro.core import (D2DNetwork, FederatedServer, ServerConfig,
                         client_deltas, global_update, make_round_fn,
                         make_scanned_rounds, mix_deltas)
 from repro.core.rounds import mask_clients
+from repro.core.sparse import ell_from_dense
 from repro.fl import ExecutionConfig, RoundPlan, make_engine, plan_rows, \
     resolve_backend
 from repro.kernels.mixing.ops import combine_weights
@@ -115,7 +116,6 @@ def test_execution_config_and_legacy_kwargs_conflict():
 
 @pytest.mark.parametrize("ecfg,effective", [
     (ExecutionConfig(backend="fused"), "aggregate"),
-    (ExecutionConfig(backend="pallas"), "aggregate"),
     (ExecutionConfig(backend="fused", record_mixed=True), "fused"),
     (ExecutionConfig(backend="einsum"), "einsum"),
     (ExecutionConfig(backend="aggregate"), "aggregate"),
@@ -133,7 +133,7 @@ def test_resolve_backend_rejects_invalid_combinations():
     with pytest.raises(ValueError, match="model_cfg"):
         resolve_backend(ExecutionConfig(backend="fused", mesh=object()))
     with pytest.raises(ValueError, match="mesh mixing"):
-        resolve_backend(ExecutionConfig(backend="pallas", mesh=object(),
+        resolve_backend(ExecutionConfig(backend="aggregate", mesh=object(),
                                         model_cfg=object()))
 
 
@@ -141,10 +141,20 @@ def test_resolve_backend_rejects_invalid_combinations():
 # straggler masks: all-ones == unmasked, bitwise, on every backend
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend",
-                         ["einsum", "pallas", "fused", "aggregate"])
+def _A_for(backend, A):
+    """The ELL backends take ``A`` as the neighbor-list pair."""
+    if backend.startswith("sparse"):
+        return tuple(jnp.asarray(a) for a in ell_from_dense(np.asarray(A)))
+    return A
+
+
+ALL_BACKENDS = ["einsum", "fused", "aggregate", "sparse", "sparse_aggregate"]
+
+
+@pytest.mark.parametrize("backend", ALL_BACKENDS)
 def test_round_fn_all_ones_active_is_bitwise_noop(backend):
     batches, A, tau, m, eta, params = _round_setup()
+    A = _A_for(backend, A)
     fn = make_round_fn(quad_loss, mixing_backend=backend, chunk=256)
     p0, mx0 = fn(params, batches, A, tau, m, eta)
     p1, mx1 = fn(params, batches, A, tau, m, eta,
@@ -167,8 +177,7 @@ def test_combine_weights_all_ones_active_is_bitwise_noop():
 # client's delta, remove its upload, renormalize by the effective count)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend",
-                         ["einsum", "pallas", "fused", "aggregate"])
+@pytest.mark.parametrize("backend", ALL_BACKENDS)
 def test_dropout_round_matches_dense_oracle(backend):
     batches, A, tau, _, eta, params = _round_setup()
     # drop a sampled client and an unsampled D2D neighbor
@@ -180,7 +189,8 @@ def test_dropout_round_matches_dense_oracle(backend):
     want = global_update(params, mixed, tau * active, m_eff)
 
     fn = make_round_fn(quad_loss, mixing_backend=backend, chunk=256)
-    got, got_mixed = fn(params, batches, A, tau, m_eff, eta, active)
+    got, got_mixed = fn(params, batches, _A_for(backend, A), tau, m_eff,
+                        eta, active)
     np.testing.assert_allclose(np.asarray(got["x"]), np.asarray(want["x"]),
                                rtol=1e-5, atol=1e-6)
     if got_mixed is not None:
@@ -273,17 +283,19 @@ def _zero_survivor_plan():
     return net, cfg, plan
 
 
-@pytest.mark.parametrize("backend",
-                         ["einsum", "pallas", "fused", "aggregate"])
+@pytest.mark.parametrize("backend", ALL_BACKENDS)
 @pytest.mark.parametrize("scan", [False, True])
 def test_zero_survivor_round_is_noop_every_backend(backend, scan):
     net, cfg, plan = _zero_survivor_plan()
+    # keep the materializing legs: unrecorded, 'fused' and 'sparse' would
+    # run as their aggregate-only upgrades
+    execution = ExecutionConfig(backend=backend, scan=scan,
+                                record_mixed=backend in ("fused", "sparse"))
 
     def run(p):
         server = FederatedServer(net, quad_loss, {"x": jnp.zeros(4)},
                                  _sampler(net.n, 4), cfg,
-                                 execution=ExecutionConfig(backend=backend,
-                                                           scan=scan))
+                                 execution=execution)
         hist = server.run(plan=p)
         return server.params, hist
 
@@ -314,7 +326,7 @@ def test_zero_survivor_round_backends_agree_bitwise():
         return np.asarray(server.params["x"])
 
     ref = run("einsum")
-    for backend in ("pallas", "fused", "aggregate"):
+    for backend in ALL_BACKENDS[1:]:
         np.testing.assert_allclose(run(backend), ref,
                                    rtol=1e-5, atol=1e-6)
 

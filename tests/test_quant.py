@@ -21,16 +21,14 @@ import pytest
 
 from repro.core import (D2DNetwork, FederatedServer, ServerConfig,
                         client_deltas, make_round_fn, make_scanned_rounds)
-from repro.core.rounds import QUANT_BACKENDS
+from repro.core.rounds import MIXING_BACKENDS
 from repro.core.sparse import SparseA
 from repro.fl import ExecutionConfig, RoundPlan, make_engine, \
     resolve_backend
 from repro.fl import packing
 from repro.fl.packing import QuantSpec
-from repro.kernels.mixing.ops import (aggregate_grouped_q,
-                                      mix_aggregate_grouped_q,
-                                      sparse_aggregate_grouped_q,
-                                      sparse_mix_aggregate_grouped_q)
+from repro.kernels.mixing.ops import (aggregate_grouped,
+                                      mix_aggregate_grouped)
 
 jax.config.update("jax_enable_x64", False)
 
@@ -243,15 +241,16 @@ def test_dense_kernels_match_oracle(storage):
     quant, spec, stored, scales, dq, A, tau, active, m = \
         _quant_inputs(storage)
     ref_mixed, ref_agg = _oracle(A, tau, m, dq, active)
-    got_mixed, got_agg = mix_aggregate_grouped_q(
-        A, tau, m, stored, scales, quant=quant, chunk=512, active=active)
+    got_mixed, got_agg = mix_aggregate_grouped(
+        A, tau, m, stored, scales=scales, quant=quant, chunk=512,
+        active=active)
     for gm, ga, rm, ra in zip(got_mixed, got_agg, ref_mixed, ref_agg):
         np.testing.assert_allclose(np.asarray(gm), rm, rtol=2e-5,
                                    atol=2e-5)
         np.testing.assert_allclose(np.asarray(ga), ra, rtol=2e-5,
                                    atol=2e-5)
-    agg_only = aggregate_grouped_q(A, tau, m, stored, scales, quant=quant,
-                                   chunk=512, active=active)
+    agg_only = aggregate_grouped(A, tau, m, stored, scales=scales,
+                                 quant=quant, chunk=512, active=active)
     for ga, ra in zip(agg_only, ref_agg):
         np.testing.assert_allclose(np.asarray(ga), ra, rtol=2e-5,
                                    atol=2e-5)
@@ -268,16 +267,16 @@ def test_sparse_kernels_match_oracle(storage):
     idx_np, w_np = SparseA.from_dense(np.asarray(A)).ell()
     idx, w = jnp.asarray(idx_np), jnp.asarray(w_np)
     ref_mixed, ref_agg = _oracle(A, tau, m, dq, active)
-    got_mixed, got_agg = sparse_mix_aggregate_grouped_q(
-        idx, w, tau, m, stored, scales, quant=quant, chunk=512,
+    got_mixed, got_agg = mix_aggregate_grouped(
+        (idx, w), tau, m, stored, scales=scales, quant=quant, chunk=512,
         active=active)
     for gm, ga, rm, ra in zip(got_mixed, got_agg, ref_mixed, ref_agg):
         np.testing.assert_allclose(np.asarray(gm), rm, rtol=2e-5,
                                    atol=2e-5)
         np.testing.assert_allclose(np.asarray(ga), ra, rtol=2e-5,
                                    atol=2e-5)
-    agg_only = sparse_aggregate_grouped_q(
-        idx, w, tau, m, stored, scales, quant=quant, chunk=512,
+    agg_only = aggregate_grouped(
+        (idx, w), tau, m, stored, scales=scales, quant=quant, chunk=512,
         active=active)
     for ga, ra in zip(agg_only, ref_agg):
         np.testing.assert_allclose(np.asarray(ga), ra, rtol=2e-5,
@@ -320,7 +319,7 @@ def test_quant_backends_agree_and_share_qstate():
     idx_np, w_np = SparseA.from_dense(np.asarray(A)).ell()
     sparse_A = (jnp.asarray(idx_np), jnp.asarray(w_np))
     results = {}
-    for backend in QUANT_BACKENDS:
+    for backend in MIXING_BACKENDS:
         fn = make_round_fn(quad_loss, mixing_backend=backend, chunk=512,
                            quant=quant)
         Aarg = sparse_A if backend.startswith("sparse") else A
@@ -337,9 +336,8 @@ def test_quant_backends_agree_and_share_qstate():
 
 
 def test_quant_round_fn_requires_qstate_and_valid_backend():
-    with pytest.raises(ValueError, match="quantized rounds"):
-        make_round_fn(quad_loss, mixing_backend="pallas",
-                      quant=QuantSpec())
+    with pytest.raises(ValueError, match="mixing_backend must be one of"):
+        make_round_fn(quad_loss, mixing_backend="nope", quant=QuantSpec())
     with pytest.raises(ValueError, match="multiple of quant.block"):
         make_round_fn(quad_loss, mixing_backend="fused", chunk=512,
                       quant=QuantSpec(block=768))
@@ -419,14 +417,13 @@ def test_plan_with_quant_validates_type():
 
 def test_resolve_backend_quant_matrix():
     q = QuantSpec()
-    # kernel backends quantize (incl. the fused->aggregate upgrade)
+    # every local backend quantizes (incl. the fused->aggregate upgrade)
     for backend in ("einsum", "fused", "aggregate", "sparse",
                     "sparse_aggregate"):
         resolve_backend(ExecutionConfig(backend=backend, quant=q))
-    # pallas kept alive by record_mixed has no packed buffers
-    with pytest.raises(ValueError, match="quantized rounds"):
-        resolve_backend(ExecutionConfig(backend="pallas",
-                                        record_mixed=True, quant=q))
+    # so does the materializing leg record_mixed keeps alive
+    assert resolve_backend(ExecutionConfig(
+        backend="fused", record_mixed=True, quant=q)) == "fused"
     # stream runtime: no well-defined EF residual for stale cohorts
     from repro.fl import StreamConfig
     with pytest.raises(ValueError, match="stream"):

@@ -12,8 +12,8 @@ kernels, and the satellite regressions that ride along:
   non-self targets);
 * the ``self_loops=False`` policy surviving the
   ``ensure_positive_out_degree`` repair in every family;
-* the shared ``m == 0`` safe-divide in ``combine_weights`` /
-  ``combine_weights_ell``.
+* the shared ``m == 0`` safe-divide in ``combine_weights``, dense and
+  ELL.
 
 Kernel parity is allclose, not bitwise: the unrolled ELL gather loop
 accumulates in neighbor order while the dense MXU matmul reduces over
@@ -254,9 +254,12 @@ def _kernel_inputs(n=13, p=37, seed=0):
 
 
 def test_sparse_mix_matches_dense():
-    A, idx, w, X, *_ = _kernel_inputs()
-    dense = ops.mix(A, X, chunk=128)
-    sparse = ops.sparse_mix(idx, w, X)
+    A, idx, w, X, tau, *_ = _kernel_inputs()
+    m = jnp.float32(1.0)
+    (dense,), _ = ops.mix_aggregate_grouped(A, tau, m, (X,), chunk=128)
+    (sparse,), _ = ops.mix_aggregate_grouped((idx, w), tau, m, (X,),
+                                             chunk=128)
+    assert sparse.dtype == X.dtype
     assert np.allclose(dense, sparse, atol=1e-5)
 
 
@@ -265,12 +268,14 @@ def test_sparse_mix_aggregate_matches_dense(masked):
     A, idx, w, X, tau, active, weights = _kernel_inputs(seed=masked)
     kw = (dict(active=active, weights=weights) if masked else {})
     m = jnp.float32(float(np.asarray(tau).sum()) or 1.0)
-    dm, da = ops.mix_aggregate(A, tau, m, X, chunk=128, **kw)
-    sm, sa = ops.sparse_mix_aggregate(idx, w, tau, m, X, chunk=128, **kw)
+    (dm,), (da,) = ops.mix_aggregate_grouped(A, tau, m, (X,), chunk=128,
+                                             **kw)
+    (sm,), (sa,) = ops.mix_aggregate_grouped((idx, w), tau, m, (X,),
+                                             chunk=128, **kw)
     assert np.allclose(dm, sm, atol=1e-5)
     assert np.allclose(da, sa, atol=1e-5)
     # aggregate-only path agrees with the fused row
-    sa2 = ops.sparse_aggregate(idx, w, tau, m, X, chunk=128, **kw)
+    (sa2,) = ops.aggregate_grouped((idx, w), tau, m, (X,), chunk=128, **kw)
     assert np.allclose(da, sa2, atol=1e-5)
 
 
@@ -278,7 +283,7 @@ def test_combine_weights_ell_matches_dense():
     A, idx, w, X, tau, active, weights = _kernel_inputs(seed=3)
     m = jnp.float32(3.0)
     dense = ops.combine_weights(A, tau, m, active, weights)
-    sparse = ops.combine_weights_ell(idx, w, tau, m, active, weights)
+    sparse = ops.combine_weights((idx, w), tau, m, active, weights)
     assert np.allclose(dense, sparse, atol=1e-6)
 
 
@@ -287,9 +292,8 @@ def test_combine_weights_m_zero_guard():
     the zero combine row, not inf/nan -- and the guard must be inert for
     m != 0 (identical to the unguarded divide)."""
     A, idx, w, X, tau, active, weights = _kernel_inputs(seed=4)
-    for fn, args in ((ops.combine_weights, (A,)),
-                     (ops.combine_weights_ell, (idx, w))):
-        row = np.asarray(fn(*args, tau, jnp.float32(0.0)))
+    for form in (A, (idx, w)):
+        row = np.asarray(ops.combine_weights(form, tau, jnp.float32(0.0)))
         assert (row == 0.0).all() and np.isfinite(row).all()
     # inert for m != 0: exactly einsum / m
     got = np.asarray(ops.combine_weights(A, tau, jnp.float32(5.0)))
@@ -302,8 +306,9 @@ def test_m_zero_guard_through_round():
     """The guard holds end to end: aggregate with m = 0 returns the
     zero row, so the global update degenerates to identity."""
     A, idx, w, X, tau, active, weights = _kernel_inputs(seed=5)
-    agg = ops.sparse_aggregate(idx, w, tau, jnp.float32(0.0), X,
-                               chunk=128)
+    (agg,) = ops.aggregate_grouped((idx, w), tau, jnp.float32(0.0), (X,),
+                                   chunk=128)
     assert (np.asarray(agg) == 0.0).all()
-    agg_d = ops.aggregate(A, tau, jnp.float32(0.0), X, chunk=128)
+    (agg_d,) = ops.aggregate_grouped(A, tau, jnp.float32(0.0), (X,),
+                                     chunk=128)
     assert (np.asarray(agg_d) == 0.0).all()

@@ -317,7 +317,7 @@ def test_buffered_closure_accepts_stragglers_late():
 
 def test_resolve_backend_stream_matrix():
     assert resolve_backend(
-        ExecutionConfig(backend="pallas", stream=StreamConfig())) \
+        ExecutionConfig(backend="aggregate", stream=StreamConfig())) \
         == "aggregate"
     assert resolve_backend(
         ExecutionConfig(backend="fused", stream=StreamConfig())) \
@@ -328,7 +328,7 @@ def test_resolve_backend_stream_matrix():
     with pytest.raises(ValueError, match="scan"):
         resolve_backend(ExecutionConfig(scan=True, stream=StreamConfig()))
     with pytest.raises(ValueError, match="record_mixed"):
-        resolve_backend(ExecutionConfig(backend="pallas",
+        resolve_backend(ExecutionConfig(backend="fused",
                                         record_mixed=True,
                                         stream=StreamConfig()))
     with pytest.raises(ValueError, match="mesh"):

@@ -27,7 +27,6 @@ from repro.kernels.mixing.fused import (aggregate_dequant_pallas,
                                         aggregate_pallas,
                                         mix_aggregate_dequant_pallas,
                                         mix_aggregate_pallas)
-from repro.kernels.mixing.mixing import mix_pallas
 
 N, N_PAD = 70, 72
 CHUNK = 2048
@@ -73,12 +72,6 @@ def test_aggregate_compiles_at_cnn_width(sds):
         fn, sds((8, N_PAD)), sds((N_PAD, P_PAD)))
 
 
-def test_mix_compiles_at_cnn_width(sds):
-    fn = functools.partial(mix_pallas, chunk=CHUNK, interpret=False)
-    assert "tpu_custom_call" in _compiled_text(
-        fn, sds((N_PAD, N_PAD)), sds((N_PAD, P_PAD)))
-
-
 def test_mix_aggregate_compiles_at_cnn_width(sds):
     fn = functools.partial(mix_aggregate_pallas, chunk=CHUNK,
                            interpret=False)
@@ -110,11 +103,11 @@ def test_mix_aggregate_dequant_compiles_at_cnn_width(sds, storage):
 
 def test_sparse_mix_aggregate_compiles_at_cnn_width(sds):
     """The ELL mix is an XLA gather; its aggregate leg is the kernel."""
-    idx, w = sds((N, 9), jnp.int32), sds((N, 9))
-    fn = functools.partial(ops.sparse_mix_aggregate, chunk=CHUNK,
+    ell = (sds((N, 9), jnp.int32), sds((N, 9)))
+    fn = functools.partial(ops.mix_aggregate_grouped, chunk=CHUNK,
                            interpret=False)
     assert "tpu_custom_call" in _compiled_text(
-        fn, idx, w, sds((N,)), sds(()), sds((N, P_PAD)))
+        fn, ell, sds((N,)), sds(()), (sds((N, P_PAD)),))
 
 
 def test_flash_attention_compiles_hd128(sds):
@@ -138,7 +131,7 @@ def _named(kernel, *args):
     return calls[0].rsplit(".", 1)[0]
 
 
-@pytest.mark.parametrize("op", ["aggregate", "mix", "mix_aggregate",
+@pytest.mark.parametrize("op", ["aggregate", "mix_aggregate",
                                 "aggregate_q", "mix_aggregate_q",
                                 "flash_attention"])
 def test_each_kernel_is_named_after_its_op(sds, op):
@@ -152,8 +145,6 @@ def test_each_kernel_is_named_after_its_op(sds, op):
     kernels = {
         "aggregate": (functools.partial(aggregate_pallas, chunk=CHUNK,
                                         interpret=False), w, X),
-        "mix": (functools.partial(mix_pallas, chunk=CHUNK,
-                                  interpret=False), A, X),
         "mix_aggregate": (functools.partial(
             mix_aggregate_pallas, chunk=CHUNK, interpret=False), A, w, X),
         "aggregate_q": (functools.partial(
