@@ -63,7 +63,7 @@ from repro.core.rounds import MIXING_BACKENDS, make_round_fn, \
     make_scanned_rounds
 from repro.core.server import History, RoundRecord
 from repro.core.sparse import SparseAseq
-from repro.spans import span
+from repro.spans import count, span
 from .distributed import MIXINGS, make_scanned_train_steps, make_train_step
 from .plan import RoundPlan
 
@@ -212,31 +212,50 @@ class Engine(Protocol):
         ...
 
 
-def _device_columns(plan: RoundPlan, sparse: bool = False):
-    """Plan columns as stacked device arrays (the scan inputs; sequential
-    execution indexes into them, which keeps the per-round values
-    identical across both drivers).
+def _host_columns(plan: RoundPlan, sparse: bool = False):
+    """Plan columns as stacked host arrays in the dtypes the round
+    programs take (float32; ELL indices int32).  The sequential drivers
+    pass row ``t`` of each as a jit argument, which slices on the host
+    and compiles nothing whatever the plan's K.
 
     ``sparse=True`` (the ELL backends) yields ``A_seq`` as the 2-tuple
-    ``(idx_seq, w_seq)`` of (K, n, d_max) device arrays -- straight from
-    a sparse plan without densifying, converted O(nnz)-wise from a dense
+    ``(idx_seq, w_seq)`` of (K, n, d_max) arrays -- straight from a
+    sparse plan without densifying, converted O(nnz)-wise from a dense
     one.  Dense backends on a sparse plan densify per round (small-n
     parity testing); at scale, keep representation and backend aligned.
     """
     if sparse:
         A = plan.A_t if plan.is_sparse else SparseAseq.from_dense(plan.A_t)
-        idx_seq, w_seq = A.ell()
-        A_seq = (jnp.asarray(idx_seq), jnp.asarray(w_seq))
+        A_seq = A.ell()
     elif plan.is_sparse:
-        A_seq = jnp.asarray(plan.A_t.dense(), jnp.float32)
+        A_seq = np.asarray(plan.A_t.dense(), np.float32)
     else:
-        A_seq = jnp.asarray(plan.A_t, jnp.float32)
-    tau_seq = jnp.asarray(plan.tau_t, jnp.float32)
-    m_seq = jnp.asarray(plan.m_t, jnp.float32)
-    eta_seq = jnp.asarray(plan.eta_t, jnp.float32)
-    active_seq = (jnp.asarray(plan.active_t, jnp.float32)
+        A_seq = np.asarray(plan.A_t, np.float32)
+    tau_seq = np.asarray(plan.tau_t, np.float32)
+    m_seq = np.asarray(plan.m_t, np.float32)
+    eta_seq = np.asarray(plan.eta_t, np.float32)
+    active_seq = (np.asarray(plan.active_t, np.float32)
                   if plan.has_dropout else None)
     return A_seq, tau_seq, m_seq, eta_seq, active_seq
+
+
+def _device_columns(plan: RoundPlan, sparse: bool = False):
+    """``_host_columns`` as device arrays: the scan drivers' inputs."""
+    return jax.tree.map(jnp.asarray, _host_columns(plan, sparse=sparse))
+
+
+def _cached(programs: Dict[Any, Callable], key, build: Callable[[], Callable]
+            ) -> Callable:
+    """The program ``programs[key]``, built by ``build()`` on the first
+    call for ``key``; counts ``engine.program_cache`` ``hit``/``miss``.
+    Each engine keeps its own ``programs``, so a later segment reuses the
+    jitted round and jax's dispatch cache hits: nothing is traced,
+    lowered or loaded again."""
+    program = programs.get(key)
+    count("engine.program_cache", "miss" if program is None else "hit")
+    if program is None:
+        program = programs[key] = build()
+    return program
 
 
 def _quant_setup(cfg: ExecutionConfig, plan: RoundPlan, params: PyTree,
@@ -323,6 +342,8 @@ class LocalEngine:
         self.cfg = cfg
         self.loss_fn = loss_fn
         self.backend = resolve_backend(cfg)
+        # round programs by (path, [K,] quant), built once (``_cached``)
+        self._programs: Dict[Any, Callable] = {}
         # filled by execute_controlled: the realized RoundPlan artifact
         self.last_realized_plan = None
 
@@ -333,24 +354,23 @@ class LocalEngine:
         K = plan.n_rounds
         sparse = self.backend in ("sparse", "sparse_aggregate")
         with span("engine.prepare"):
-            A_seq, tau_seq, m_seq, eta_seq, active_seq = _device_columns(
+            columns = _device_columns if cfg.scan else _host_columns
+            A_seq, tau_seq, m_seq, eta_seq, active_seq = columns(
                 plan, sparse=sparse)
             history = History(algorithm=plan.algorithm,
                               ledger=CommLedger(energy_ratio=energy_ratio))
             quant, qstate = _quant_setup(cfg, plan, params, self.backend)
             if cfg.scan:
-                scanned = make_scanned_rounds(
-                    self.loss_fn, K, jit=cfg.jit,
-                    mixing_backend=self.backend, chunk=cfg.chunk,
-                    interpret=cfg.interpret, quant=quant)
+                scanned = _cached(
+                    self._programs, ("scan", K, quant),
+                    lambda: make_scanned_rounds(
+                        self.loss_fn, K, jit=cfg.jit,
+                        mixing_backend=self.backend, chunk=cfg.chunk,
+                        interpret=cfg.interpret, quant=quant))
                 batches_seq = jax.tree.map(lambda *bs: jnp.stack(bs),
                                            *batches)
             else:
-                round_fn = make_round_fn(self.loss_fn, jit=cfg.jit,
-                                         mixing_backend=self.backend,
-                                         chunk=cfg.chunk,
-                                         interpret=cfg.interpret,
-                                         quant=quant)
+                round_fn = self._round_fn(quant)
 
         if cfg.scan:
             with span("engine.dispatch", round=plan.t0):
@@ -385,11 +405,21 @@ class LocalEngine:
                            eval_fn, eval_every)
         return params, history
 
+    def _round_fn(self, quant):
+        """This engine's sequential round program for ``quant``."""
+        cfg = self.cfg
+        return _cached(
+            self._programs, ("round", quant),
+            lambda: make_round_fn(self.loss_fn, jit=cfg.jit,
+                                  mixing_backend=self.backend,
+                                  chunk=cfg.chunk, interpret=cfg.interpret,
+                                  quant=quant))
+
     def execute_controlled(self, loop, params, batches, *, eval_fn=None,
                            eval_every=1, energy_ratio=0.1):
         """Closed-loop execution: one ``repro.control.ControlLoop`` row
         per round, realized through the same jitted round function as
-        ``execute`` with per-round device arrays carrying identical
+        ``execute`` with per-round arrays carrying identical
         values -- so replaying ``self.last_realized_plan`` (set on
         return) through ``execute`` reproduces this run bitwise (the
         replay's records merely lack the live ``control`` telemetry).
@@ -419,9 +449,7 @@ class LocalEngine:
         K = len(batches)
         history = History(algorithm=loop.algorithm,
                           ledger=CommLedger(energy_ratio=energy_ratio))
-        round_fn = make_round_fn(self.loss_fn, jit=cfg.jit,
-                                 mixing_backend=self.backend,
-                                 chunk=cfg.chunk, interpret=cfg.interpret)
+        round_fn = self._round_fn(None)
         needs_deltas = loop.needs_deltas
         for t in range(K):
             row, telemetry = loop.next_row()
@@ -470,6 +498,8 @@ class MeshEngine:
                              "unsupported on the mesh runtime")
         self.cfg = cfg
         self.backend = resolve_backend(cfg)
+        # train steps by (path, [K,] quant), built once (``_cached``)
+        self._programs: Dict[Any, Callable] = {}
 
     def execute(self, plan, params, batches, *, eval_fn=None, eval_every=1,
                 energy_ratio=0.1):
@@ -477,22 +507,26 @@ class MeshEngine:
         cfg = self.cfg
         K = plan.n_rounds
         with span("engine.prepare"):
-            A_seq, tau_seq, m_seq, eta_seq, active_seq = _device_columns(
-                plan)
+            columns = _device_columns if cfg.scan else _host_columns
+            A_seq, tau_seq, m_seq, eta_seq, active_seq = columns(plan)
             history = History(algorithm=plan.algorithm,
                               ledger=CommLedger(energy_ratio=energy_ratio))
             quant, qstate = _quant_setup(cfg, plan, params, self.backend,
                                          mesh=cfg.mesh)
             if cfg.scan:
-                scanned = make_scanned_train_steps(
-                    cfg.model_cfg, cfg.mesh, K, mixing=self.backend,
-                    jit=cfg.jit, quant=quant)
+                scanned = _cached(
+                    self._programs, ("scan", K, quant),
+                    lambda: make_scanned_train_steps(
+                        cfg.model_cfg, cfg.mesh, K, mixing=self.backend,
+                        jit=cfg.jit, quant=quant))
                 tokens_seq = jax.tree.map(lambda *bs: jnp.stack(bs),
                                           *batches)
             else:
-                step = make_train_step(cfg.model_cfg, cfg.mesh,
-                                       mixing=self.backend, jit=cfg.jit,
-                                       quant=quant)
+                step = _cached(
+                    self._programs, ("step", quant),
+                    lambda: make_train_step(cfg.model_cfg, cfg.mesh,
+                                            mixing=self.backend, jit=cfg.jit,
+                                            quant=quant))
 
         if cfg.scan:
             with span("engine.dispatch", round=plan.t0):

@@ -8,9 +8,10 @@ of the program (the benchmark's or an operator's own
 Nothing else happens unless a ``Recorder`` is attached with
 ``recording(recorder)``: then each span also appends a ``Record`` (name,
 ``time.perf_counter`` start and end, the enclosing span's name and the
-round), and the compile events jax reports add their seconds to the
-recorder's counters.  With no recorder attached a span reads no clock
-and keeps nothing, and no listener is registered with jax.
+round), the compile events jax reports add their seconds to the
+recorder's counters, and ``count(name, key)`` bumps the counter
+``(name, key)``.  With no recorder attached a span reads no clock, a
+count keeps nothing, and no listener is registered with jax.
 """
 
 from __future__ import annotations
@@ -23,8 +24,8 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax
 
-__all__ = ["COMPILE_EVENTS", "PREFIX", "Record", "Recorder", "recording",
-           "span"]
+__all__ = ["COMPILE_EVENTS", "PREFIX", "Record", "Recorder", "count",
+           "recording", "span"]
 
 PREFIX = "repro."
 
@@ -48,8 +49,10 @@ class Record:
 
 @dataclasses.dataclass
 class Recorder:
-    """Spans and compile counters kept in memory while attached;
-    ``counters[(event, fun_name)]`` is ``[seconds, count]``."""
+    """Spans and counters kept in memory while attached;
+    ``counters[(event, fun_name)]`` is ``[seconds, count]``, and a
+    program's own ``counters[(name, key)]`` (``count``) is ``[0.0,
+    count]``."""
     spans: List[Record] = dataclasses.field(default_factory=list)
     counters: Dict[Tuple[str, str], List[float]] = dataclasses.field(
         default_factory=dict)
@@ -92,6 +95,14 @@ def span(name: str, round: Optional[int] = None) -> Iterator[None]:
             t1 = time.perf_counter()
             names.pop()
             rec.spans.append(Record(name, t0, t1, parent, round))
+
+
+def count(name: str, key: str) -> None:
+    """Count one ``key`` outcome of ``name`` (``engine.program_cache``
+    ``hit``) in the attached recorder; nothing without one."""
+    rec = _recorder
+    if rec is not None:
+        rec.counters.setdefault((name, key), [0.0, 0])[1] += 1
 
 
 @contextlib.contextmanager

@@ -87,6 +87,19 @@ def test_compile_counters_fill_only_while_recording():
     assert rec.counters == counted
 
 
+def test_program_counts_fill_only_while_recording():
+    rec = spans.Recorder()
+    spans.count("engine.program_cache", "miss")
+    with spans.recording(rec):
+        spans.count("engine.program_cache", "miss")
+        spans.count("engine.program_cache", "hit")
+        spans.count("engine.program_cache", "hit")
+    spans.count("engine.program_cache", "hit")
+    assert rec.counters == {("engine.program_cache", "miss"): [0.0, 1],
+                            ("engine.program_cache", "hit"): [0.0, 2]}
+    assert rec.spans == []
+
+
 def _linear_loss(params, batch):
     x, y = batch
     logits = x.reshape(x.shape[0], -1) @ params["w"]
