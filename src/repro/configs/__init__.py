@@ -9,7 +9,7 @@ from typing import Dict, List
 
 from repro.models.config import ModelConfig
 
-from . import (deepseek_v2_236b, internvl2_1b, mamba2_1_3b, musicgen_large,
+from . import (deepseek_v2_236b, deepseek_v2_lite, internvl2_1b, mamba2_1_3b, musicgen_large,
                phi35_moe_42b, qwen1_5_4b, qwen2_7b, qwen3_32b, stablelm_1_6b,
                zamba2_2_7b)
 
@@ -20,6 +20,7 @@ _REGISTRY = {
     "internvl2-1b": internvl2_1b.config,
     "zamba2-2.7b": zamba2_2_7b.config,
     "deepseek-v2-236b": deepseek_v2_236b.config,
+    "deepseek-v2-lite": deepseek_v2_lite.config,
     "phi3.5-moe-42b-a6.6b": phi35_moe_42b.config,
     "qwen1.5-4b": qwen1_5_4b.config,
     "qwen2-7b": qwen2_7b.config,

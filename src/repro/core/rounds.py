@@ -51,6 +51,19 @@ forms of ``A`` apart by type.  Every backend also takes a quantized
 payload (``quant``): the kernels dequantize in VMEM, the einsum oracle
 on the host side of the wire.
 
+Cohort forms (``make_round_fn(..., cohort=...)``): 'vmap' (the default)
+trains all n clients at once under ``jax.vmap``, which keeps about
+``(1 + 2n)`` param copies live.  'sequential' trains them one at a time
+under ``jax.lax.scan`` over clients: each client runs its T steps from
+the global params, and an fp32 accumulator adds ``w_j * Delta_j`` with
+``w = (tau^T A) / m`` (``kernels.mixing.ops.combine_weights``, the
+eq.-4 combine row the aggregate kernels use) as each client finishes;
+the round then applies it to the global params.  About five param
+copies stay live whatever n is.  It serves the aggregate-only backends
+('aggregate', 'sparse_aggregate') without quantization: the mixed
+deltas are never formed.  ``repro.fl.engine.LocalEngine`` picks the form
+from the params' bytes and the device's memory.
+
 ``make_scanned_rounds`` wraps the round in ``jax.lax.scan`` over stacked
 ``(A_t, tau_t, m_t, eta_t[, active_t])`` sequences so a K-round
 trajectory dispatches to the device once instead of once per round.
@@ -85,6 +98,7 @@ __all__ = [
     "make_round_fn",
     "make_scanned_rounds",
     "MIXING_BACKENDS",
+    "COHORTS",
 ]
 
 PyTree = Any
@@ -92,6 +106,7 @@ LossFn = Callable[[PyTree, PyTree], jnp.ndarray]  # (params, batch) -> scalar
 
 MIXING_BACKENDS = ("einsum", "fused", "aggregate", "sparse",
                    "sparse_aggregate")
+COHORTS = ("vmap", "sequential")
 
 
 def local_sgd(loss_fn: LossFn, params: PyTree, batches: PyTree,
@@ -267,9 +282,78 @@ def _mix_and_update(global_params, deltas, A, tau, m, *, backend, chunk,
             packing.unpack(mixed_bufs, spec), qstate)
 
 
+def _check_cohort(cohort: str, mixing_backend: str, quant) -> None:
+    if cohort not in COHORTS:
+        raise ValueError(f"cohort must be one of {COHORTS}, got {cohort!r}")
+    if cohort == "sequential" and (
+            quant is not None
+            or mixing_backend not in ("aggregate", "sparse_aggregate")):
+        raise ValueError(
+            "the sequential cohort accumulates the eq.-4 aggregate client "
+            "by client and never forms the mixed deltas or a quantized "
+            "payload: it takes the 'aggregate' or 'sparse_aggregate' "
+            f"backend without quant, got {mixing_backend!r} with "
+            f"quant={quant!r}")
+
+
+def sequential_aggregate(loss_fn: LossFn, global_params: PyTree,
+                         client_batches: PyTree, A, tau: jnp.ndarray,
+                         m: jnp.ndarray, eta: jnp.ndarray,
+                         active: Optional[jnp.ndarray] = None) -> PyTree:
+    """The round's aggregate with the clients trained one at a time
+    (module docstring, 'sequential'): ``sum_j w_j (x_j - x)``, accumulated
+    in fp32 under ``lax.scan`` over the client axis of ``client_batches``."""
+    from repro.kernels.mixing import ops
+
+    w = ops.combine_weights(A, tau, m, active)
+    f32 = jnp.float32
+
+    def client(acc, xs):
+        batches, w_j = xs
+        with jax.named_scope("local_sgd"):
+            final = local_sgd(loss_fn, global_params, batches, eta)
+        with jax.named_scope("mix"):
+            return jax.tree.map(
+                lambda a, f, g: a + w_j * (f - g).astype(f32),
+                acc, final, global_params), None
+
+    acc = jax.tree.map(lambda g: jnp.zeros(g.shape, f32), global_params)
+    acc, _ = jax.lax.scan(client, acc, (client_batches, w))
+    return acc
+
+
+def _sequential_round_fn(loss_fn: LossFn, jit: bool):
+    """The 'sequential' round: ``sequential_aggregate``, then ``x + acc``
+    cast back to each leaf's dtype.  Jitted, these are two programs, and
+    the second writes the new params into the buffers of the global
+    params it consumes (donated), so that no caller's reference keeps a
+    third copy alive into the next round.  The first is the round's
+    ``accumulate`` attribute."""
+    accumulate = functools.partial(sequential_aggregate, loss_fn)
+
+    def apply(global_params, acc):
+        with jax.named_scope("global_update"):
+            return jax.tree.map(lambda g, a: (g + a).astype(g.dtype),
+                                global_params, acc)
+
+    if jit:
+        accumulate = jax.jit(accumulate)
+        apply = jax.jit(apply, donate_argnums=0)
+
+    def round_fn(global_params, client_batches, A, tau, m, eta,
+                 active=None, qstate=None):
+        acc = accumulate(global_params, client_batches, A, tau, m, eta,
+                         active)
+        return apply(global_params, acc), None
+
+    round_fn.accumulate = accumulate
+    return round_fn
+
+
 def make_round_fn(loss_fn: LossFn, jit: bool = True,
                   mixing_backend: str = "einsum", *, chunk: int = 2048,
-                  interpret: Optional[bool] = None, quant=None):
+                  interpret: Optional[bool] = None, quant=None,
+                  cohort: str = "vmap"):
     """Build the jitted global-round function.
 
     Signature: ``round_fn(global_params, client_batches, A, tau, m, eta[,
@@ -298,14 +382,23 @@ def make_round_fn(loss_fn: LossFn, jit: bool = True,
     returns ``(new_global_params, mixed_deltas, new_qstate)``; ``chunk``
     must then be a multiple of ``quant.block``.  With ``quant=None``
     nothing about the unquantized path changes.
+
+    ``cohort='sequential'`` trains the clients one at a time (module
+    docstring); it takes the aggregate-only backends without ``quant``,
+    returns ``(new_global_params, None)`` and, jitted, consumes the
+    global params it is given (``_sequential_round_fn``).
     """
     if mixing_backend not in MIXING_BACKENDS:
         raise ValueError(
             f"mixing_backend must be one of {MIXING_BACKENDS}, "
             f"got {mixing_backend!r}")
+    _check_cohort(cohort, mixing_backend, quant)
     if quant is not None:
         from repro.kernels.mixing.ops import _check_quant_chunk
         _check_quant_chunk(quant, chunk)
+
+    if cohort == "sequential":
+        return _sequential_round_fn(loss_fn, jit)
 
     def round_fn(global_params: PyTree, client_batches: PyTree,
                  A: jnp.ndarray, tau: jnp.ndarray, m: jnp.ndarray,
@@ -332,7 +425,8 @@ def make_round_fn(loss_fn: LossFn, jit: bool = True,
 def make_scanned_rounds(loss_fn: LossFn, K: int, jit: bool = True,
                         mixing_backend: str = "einsum", *,
                         chunk: int = 2048,
-                        interpret: Optional[bool] = None, quant=None):
+                        interpret: Optional[bool] = None, quant=None,
+                        cohort: str = "vmap"):
     """Build a driver that runs ``K`` global rounds in one ``lax.scan``.
 
     The host builds the whole time-varying topology sequence up front and
@@ -363,7 +457,7 @@ def make_scanned_rounds(loss_fn: LossFn, K: int, jit: bool = True,
     """
     round_fn = make_round_fn(loss_fn, jit=False,
                              mixing_backend=mixing_backend, chunk=chunk,
-                             interpret=interpret, quant=quant)
+                             interpret=interpret, quant=quant, cohort=cohort)
 
     def scanned(global_params: PyTree, client_batches_seq: PyTree,
                 A_seq: jnp.ndarray, tau_seq: jnp.ndarray,

@@ -38,6 +38,14 @@ Every local backend takes a quantized payload; on the mesh only the
 one-pass 'fused' / 'fused_rs' schedules do, and the stream runtime takes
 none.
 
+Cohort form (LocalEngine): the round trains all n clients under
+``jax.vmap`` where the ``(1 + 2n)`` param copies that keeps live fit the
+device's memory (``memory_stats()["bytes_limit"]``; a device that reports
+none counts as roomy), and one client at a time under ``lax.scan``
+otherwise (``repro.core.rounds``, 'sequential'), which takes the
+aggregate-only backends without quant.  Each ``execute`` counts its form
+as ``engine.cohort`` ``vmap`` / ``sequential``.
+
 The sparse backends consume the plan's ``A_t`` column in ELL form
 (``repro.core.sparse``) -- a sparse plan never densifies on this path,
 which is what lets ``n`` scale past the dense O(n^2) wall.
@@ -68,7 +76,7 @@ from .distributed import MIXINGS, make_scanned_train_steps, make_train_step
 from .plan import RoundPlan
 
 __all__ = ["ExecutionConfig", "Engine", "LocalEngine", "MeshEngine",
-           "make_engine", "resolve_backend"]
+           "cohort_form", "make_engine", "resolve_backend"]
 
 PyTree = Any
 EvalFn = Callable[[PyTree], Dict[str, float]]
@@ -258,6 +266,23 @@ def _cached(programs: Dict[Any, Callable], key, build: Callable[[], Callable]
     return program
 
 
+def _bytes_limit() -> Optional[int]:
+    """The default device's memory in bytes, where it reports one."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
+def cohort_form(params: PyTree, n: int, bytes_limit: Optional[int]) -> str:
+    """'vmap' where the ``(1 + 2n)`` param copies of the vmapped round
+    (the globals, n clients' params and n deltas) fit ``bytes_limit``,
+    else 'sequential'; 'vmap' when the limit is unknown."""
+    if bytes_limit is None:
+        return "vmap"
+    size = sum(int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
+               for leaf in jax.tree.leaves(params))
+    return "vmap" if (1 + 2 * n) * size <= bytes_limit else "sequential"
+
+
 def _quant_setup(cfg: ExecutionConfig, plan: RoundPlan, params: PyTree,
                  backend: str, mesh=None):
     """Resolve the effective quant config (cfg overrides plan) and build
@@ -342,10 +367,16 @@ class LocalEngine:
         self.cfg = cfg
         self.loss_fn = loss_fn
         self.backend = resolve_backend(cfg)
-        # round programs by (path, [K,] quant), built once (``_cached``)
+        # round programs by (path, [K,] cohort, quant), built once
+        # (``_cached``)
         self._programs: Dict[Any, Callable] = {}
         # filled by execute_controlled: the realized RoundPlan artifact
         self.last_realized_plan = None
+
+    def _cohort(self, params, n: int) -> str:
+        form = cohort_form(params, n, _bytes_limit())
+        count("engine.cohort", form)
+        return form
 
     def execute(self, plan, params, batches, *, eval_fn=None, eval_every=1,
                 energy_ratio=0.1):
@@ -360,17 +391,19 @@ class LocalEngine:
             history = History(algorithm=plan.algorithm,
                               ledger=CommLedger(energy_ratio=energy_ratio))
             quant, qstate = _quant_setup(cfg, plan, params, self.backend)
+            cohort = self._cohort(params, plan.n_clients)
             if cfg.scan:
                 scanned = _cached(
-                    self._programs, ("scan", K, quant),
+                    self._programs, ("scan", K, cohort, quant),
                     lambda: make_scanned_rounds(
                         self.loss_fn, K, jit=cfg.jit,
                         mixing_backend=self.backend, chunk=cfg.chunk,
-                        interpret=cfg.interpret, quant=quant))
+                        interpret=cfg.interpret, quant=quant,
+                        cohort=cohort))
                 batches_seq = jax.tree.map(lambda *bs: jnp.stack(bs),
                                            *batches)
             else:
-                round_fn = self._round_fn(quant)
+                round_fn = self._round_fn(quant, cohort)
 
         if cfg.scan:
             with span("engine.dispatch", round=plan.t0):
@@ -405,15 +438,15 @@ class LocalEngine:
                            eval_fn, eval_every)
         return params, history
 
-    def _round_fn(self, quant):
-        """This engine's sequential round program for ``quant``."""
+    def _round_fn(self, quant, cohort):
+        """This engine's per-round program for ``quant`` and ``cohort``."""
         cfg = self.cfg
         return _cached(
-            self._programs, ("round", quant),
+            self._programs, ("round", cohort, quant),
             lambda: make_round_fn(self.loss_fn, jit=cfg.jit,
                                   mixing_backend=self.backend,
                                   chunk=cfg.chunk, interpret=cfg.interpret,
-                                  quant=quant))
+                                  quant=quant, cohort=cohort))
 
     def execute_controlled(self, loop, params, batches, *, eval_fn=None,
                            eval_every=1, energy_ratio=0.1):
@@ -449,7 +482,7 @@ class LocalEngine:
         K = len(batches)
         history = History(algorithm=loop.algorithm,
                           ledger=CommLedger(energy_ratio=energy_ratio))
-        round_fn = self._round_fn(None)
+        round_fn = self._round_fn(None, self._cohort(params, loop.n))
         needs_deltas = loop.needs_deltas
         for t in range(K):
             row, telemetry = loop.next_row()

@@ -32,6 +32,13 @@ class ModelConfig:
     qkv_bias: bool = False                  # qwen1.5 / qwen2 / internvl2
     rope_theta: float = 10_000.0
     rope_fraction: float = 1.0              # stablelm-2 uses 0.25
+    # YaRN rope scaling (deepseek-v2 ``rope_scaling``; 0 = plain rope)
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     norm_type: str = "rms"                  # 'rms' | 'layer'
     mlp_type: str = "swiglu"                # 'swiglu' | 'gelu'
     sliding_window: Optional[int] = None    # static window; long-context decode
@@ -53,6 +60,13 @@ class ModelConfig:
     moe_impl: str = "ragged"                # 'ragged' | 'dense' (oracle)
     moe_chunk: int = 0                      # token-chunked dispatch (0 = off)
     router_aux_weight: float = 0.01
+    norm_topk_prob: bool = True             # renormalize the top-k gates
+    routed_scaling: float = 1.0             # multiplies the routed gates
+    # expert share (expert parallelism): this layer holds experts
+    # [expert_offset, expert_offset + experts_held) of the n_experts it
+    # routes over and computes only their part; 0 holds them all
+    experts_held: int = 0
+    expert_offset: int = 0
 
     # --- SSM (mamba2 / SSD) ---------------------------------------------------
     ssm_state: int = 0
@@ -91,6 +105,18 @@ class ModelConfig:
                 raise ValueError("n_heads must be divisible by n_kv_heads")
         if self.family == "moe" and not self.n_experts:
             raise ValueError("moe family needs n_experts")
+        if self.experts_held and not (
+                0 <= self.expert_offset
+                and self.expert_offset + self.experts_held <= self.n_experts):
+            raise ValueError(
+                f"held experts [{self.expert_offset}, "
+                f"{self.expert_offset + self.experts_held}) are not a "
+                f"range of the {self.n_experts} experts")
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts this layer holds (all of them by default)."""
+        return self.experts_held or self.n_experts
 
     @property
     def resolved_head_dim(self) -> int:
@@ -142,6 +168,7 @@ class ModelConfig:
                            nope_head_dim=32, v_head_dim=32)
         if self.n_experts:
             changes.update(n_experts=4, experts_per_token=2,
+                           experts_held=0, expert_offset=0,
                            n_shared_experts=min(self.n_shared_experts, 1),
                            moe_d_ff=d_model * 2,
                            first_dense_layers=min(self.first_dense_layers, 1),
@@ -189,7 +216,9 @@ class ModelConfig:
             return 2 * d * ff
 
         def moe_layer() -> int:
-            routed = self.n_experts if not active_only else self.experts_per_token
+            routed = (self.held_experts if not active_only
+                      else self.experts_per_token * self.held_experts
+                      / self.n_experts)
             p = routed * 3 * d * self.moe_d_ff
             p += self.n_shared_experts * 3 * d * self.moe_d_ff
             p += d * self.n_experts  # router

@@ -3,15 +3,17 @@
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 PyTree = Any
 
 __all__ = ["rms_norm", "layer_norm", "norm", "norm_init", "rope_angles",
-           "apply_rope",
+           "apply_rope", "yarn_inv_freq", "yarn_mscale",
            "mlp_init", "mlp_apply", "dense_init", "he_normal", "lecun_normal"]
 
 
@@ -79,12 +81,42 @@ def norm(x, p, kind: str = "rms", eps: float = 1e-6):
 # Rotary position embeddings
 # ---------------------------------------------------------------------------
 
-def rope_angles(positions: jnp.ndarray, dim: int, theta: float = 10_000.0):
-    """positions (...,) -> (cos, sin) of shape (..., dim//2)."""
+def rope_angles(positions: jnp.ndarray, dim: int, theta: float = 10_000.0,
+                inv_freq: Optional[jnp.ndarray] = None):
+    """positions (...,) -> (cos, sin) of shape (..., dim//2); ``inv_freq``
+    (dim//2,) replaces the plain frequencies (``yarn_inv_freq``)."""
     half = dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = (1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+             if inv_freq is None else inv_freq)
     ang = positions.astype(jnp.float32)[..., None] * freqs
     return jnp.cos(ang), jnp.sin(ang)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor ``0.1 mscale ln(factor) + 1`` (1 at
+    ``factor <= 1``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float) -> jnp.ndarray:
+    """YaRN frequencies of ``dim`` rotary dims (DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``): dims whose wavelength fits
+    ``original`` positions ``beta_fast`` times or more keep the plain
+    frequency, those below ``beta_slow`` times take it over ``factor``,
+    and a linear ramp blends the ones between."""
+    half = dim // 2
+    extra = theta ** (-np.arange(half, dtype=np.float64) * 2 / dim)
+    inter = extra / factor
+
+    def corr(rotations):
+        return (dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    return jnp.asarray(inter * ramp + extra * (1 - ramp), jnp.float32)
 
 
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,

@@ -21,7 +21,8 @@ import jax.numpy as jnp
 
 from .attention import NEG_INF
 from .config import ModelConfig
-from .layers import lecun_normal, rms_norm, rope_angles
+from .layers import (lecun_normal, rms_norm, rope_angles, yarn_inv_freq,
+                     yarn_mscale)
 
 PyTree = Any
 
@@ -48,6 +49,34 @@ def mla_init(key, cfg: ModelConfig, dtype) -> PyTree:
     return p
 
 
+def _rope(cfg: ModelConfig, positions):
+    """cos, sin of the rope dims: YaRN frequencies where the config scales
+    rope (its cos/sin factor ``mscale(f, mscale) / mscale(f,
+    mscale_all_dim)`` is 1 whenever the two are equal, as published)."""
+    dr = cfg.rope_head_dim
+    inv_freq = None
+    if cfg.yarn_factor:
+        inv_freq = yarn_inv_freq(dr, cfg.rope_theta, cfg.yarn_factor,
+                                 cfg.yarn_original_max_pos,
+                                 cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    cos, sin = rope_angles(positions, dr, cfg.rope_theta, inv_freq)
+    if cfg.yarn_factor:
+        m = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+             / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
+    return cos, sin
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    """``(dn + dr)^-0.5``, times YaRN's ``mscale(f, mscale_all_dim)^2``
+    where the config scales rope."""
+    scale = (cfg.nope_head_dim + cfg.rope_head_dim) ** -0.5
+    if cfg.yarn_factor:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
 def _queries(cfg: ModelConfig, p: PyTree, x, positions):
     """-> q_nope (B,S,H,dn), q_rope (B,S,H,dr) (roped)."""
     B, S, _ = x.shape
@@ -58,7 +87,7 @@ def _queries(cfg: ModelConfig, p: PyTree, x, positions):
         q = x @ p["wq"]
     q = q.reshape(B, S, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    cos, sin = rope_angles(positions, dr, cfg.rope_theta)
+    cos, sin = _rope(cfg, positions)
     c = cos[..., None, :].astype(q_rope.dtype)
     s = sin[..., None, :].astype(q_rope.dtype)
     half = dr // 2
@@ -73,7 +102,7 @@ def _latents(cfg: ModelConfig, p: PyTree, x, positions):
     kv = x @ p["wkv_a"]
     c_kv = rms_norm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
     k_rope = kv[..., r:]
-    cos, sin = rope_angles(positions, dr, cfg.rope_theta)
+    cos, sin = _rope(cfg, positions)
     half = dr // 2
     k1, k2 = k_rope[..., :half], k_rope[..., half:]
     c = cos.astype(k_rope.dtype)
@@ -86,7 +115,13 @@ def mla_full(cfg: ModelConfig, p: PyTree, x: jnp.ndarray,
              positions: jnp.ndarray,
              window: Optional[int] = "cfg") -> jnp.ndarray:
     """Full-sequence causal MLA (training / prefill): materializes per-head
-    K/V from the latent (the flop-efficient choice when S == #queries)."""
+    K/V from the latent (the flop-efficient choice when S == #queries).
+    Runs under ``jax.named_scope("mla")``."""
+    with jax.named_scope("mla"):
+        return _mla_full(cfg, p, x, positions, window)
+
+
+def _mla_full(cfg, p, x, positions, window):
     if window == "cfg":
         window = cfg.sliding_window
     B, S, _ = x.shape
@@ -97,7 +132,7 @@ def mla_full(cfg: ModelConfig, p: PyTree, x: jnp.ndarray,
     kv = (c_kv @ p["wkv_b"]).reshape(B, S, H, dn + dv)
     k_nope, v = kv[..., :dn], kv[..., dn:]
 
-    scale = (dn + dr) ** -0.5
+    scale = softmax_scale(cfg)
     C = min(cfg.attn_chunk, S) if cfg.attn_impl == "chunked" else S
     if cfg.attn_impl == "chunked" and C < S:
         # query-chunked: scores stay at (B, H, C, S), never (B, H, S, S);
@@ -191,7 +226,7 @@ def mla_decode(cfg: ModelConfig, p: PyTree, x: jnp.ndarray, cache: PyTree,
         ok &= age < window
     mask = jnp.where(ok, 0.0, NEG_INF)
 
-    scale = (dn + dr) ** -0.5
+    scale = softmax_scale(cfg)
     scores = (jnp.einsum("bshr,btr->bhst", q_lat, ckv,
                          preferred_element_type=jnp.float32)
               + jnp.einsum("bshd,btd->bhst", q_rope, krope,

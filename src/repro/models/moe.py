@@ -18,6 +18,24 @@ variant is evaluated in the §Perf hillclimb.
 Shared experts (DeepSeek-V2) are a plain always-on SwiGLU branch.
 The auxiliary load-balance loss is the Switch/GShard form
 ``E * sum_e f_e * P_e``.
+
+Gates: the top-k softmax probabilities, renormalized to sum to one
+unless ``cfg.norm_topk_prob`` is off (DeepSeek-V2 keeps them raw), then
+times ``cfg.routed_scaling``.
+
+Expert share (expert parallelism's per-chip layer): with
+``cfg.experts_held`` set the layer holds only the routed experts
+``[expert_offset, expert_offset + experts_held)`` (their weights lead
+with that many rows), routes every token over all ``n_experts`` at the
+router's full width, and computes the part of the result its own experts
+give: ``routed_scaling * sum_{e in top-k and held} g_e FFN_e(x)``, plus
+the shared experts, which every share computes alike.  What the absent
+experts would add is left out.  The ``ragged`` path sorts the held slots
+first, by expert, into grouped matmuls over the held experts alone; the
+other slots ride behind the groups and are zeroed.  No token is dropped.
+
+Named scopes: ``moe_router`` (router, top-k, gates, aux loss),
+``moe_experts`` (dispatch, grouped matmuls, combine) and ``moe_shared``.
 """
 
 from __future__ import annotations
@@ -36,10 +54,10 @@ __all__ = ["moe_init", "moe_apply"]
 
 
 def moe_init(key, cfg: ModelConfig, dtype) -> PyTree:
-    d, ff, E = cfg.d_model, cfg.moe_d_ff, cfg.n_experts
+    d, ff, E = cfg.d_model, cfg.moe_d_ff, cfg.held_experts
     ks = jax.random.split(key, 5)
     p = {
-        "router": lecun_normal(ks[0], (d, E), jnp.float32),
+        "router": lecun_normal(ks[0], (d, cfg.n_experts), jnp.float32),
         "gate": lecun_normal(ks[1], (E, d, ff), dtype) / jnp.sqrt(1.0),
         "up": lecun_normal(ks[2], (E, d, ff), dtype),
         "down": lecun_normal(ks[3], (E, ff, d), dtype),
@@ -60,7 +78,10 @@ def _route(cfg: ModelConfig, p: PyTree, xf: jnp.ndarray):
     logits = xf.astype(jnp.float32) @ p["router"]
     probs = jax.nn.softmax(logits, axis=-1)
     w, ids = jax.lax.top_k(probs, k)
-    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if cfg.norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if cfg.routed_scaling != 1.0:
+        w = w * cfg.routed_scaling
     # Switch-style load-balance: f_e = token fraction routed to e,
     # P_e = mean router probability of e.
     onehot = jax.nn.one_hot(ids, E, dtype=jnp.float32)      # (T, k, E)
@@ -71,36 +92,48 @@ def _route(cfg: ModelConfig, p: PyTree, xf: jnp.ndarray):
 
 
 def _experts_ragged(p: PyTree, xs: jnp.ndarray, group_sizes: jnp.ndarray,
-                    dtype) -> jnp.ndarray:
-    """Grouped SwiGLU over expert-sorted rows xs (Tk, d)."""
-    g = jax.lax.ragged_dot(xs, p["gate"], group_sizes)
-    u = jax.lax.ragged_dot(xs, p["up"], group_sizes)
+                    dtype, keep=None) -> jnp.ndarray:
+    """Grouped SwiGLU over expert-sorted rows xs (Tk, d).  ``keep``
+    (Tk, 1) marks the rows inside the groups when they do not cover xs:
+    the grouped matmuls leave the others undefined, so they are zeroed
+    after each (and xs before), which keeps them out of the gradients."""
+    mask = (lambda a: a) if keep is None else (
+        lambda a: jnp.where(keep, a, jnp.zeros((), a.dtype)))
+    xs = mask(xs)
+    g = mask(jax.lax.ragged_dot(xs, p["gate"], group_sizes))
+    u = mask(jax.lax.ragged_dot(xs, p["up"], group_sizes))
     h = (jax.nn.silu(g) * u).astype(dtype)
-    return jax.lax.ragged_dot(h, p["down"], group_sizes)
+    return mask(jax.lax.ragged_dot(h, p["down"], group_sizes))
 
 
 def _dispatch_ragged(cfg: ModelConfig, p: PyTree, xf, w, ids) -> jnp.ndarray:
     """Expert dispatch + grouped GEMMs + combine for pre-routed tokens."""
     T, d = xf.shape
-    k, E = cfg.experts_per_token, cfg.n_experts
+    k, E = cfg.experts_per_token, cfg.held_experts
     flat_ids = ids.reshape(-1)                        # (T*k,)
+    held = None
+    if cfg.experts_held:
+        # the held experts' slots first, by local id; the rest after
+        local = flat_ids - cfg.expert_offset
+        held = (local >= 0) & (local < E)
+        flat_ids = jnp.where(held, local, E)
     order = jnp.argsort(flat_ids)                     # stable
     token_of = order // k                             # source row per slot
     xs = jnp.take(xf, token_of, axis=0)               # (T*k, d)
     group_sizes = jnp.bincount(flat_ids, length=E).astype(jnp.int32)
-    ys = _experts_ragged(p, xs, group_sizes, xf.dtype)
+    keep = None if held is None else jnp.take(held, order)[:, None]
+    ys = _experts_ragged(p, xs, group_sizes, xf.dtype, keep)
     wflat = jnp.take(w.reshape(-1), order)            # weight per sorted slot
     return jnp.zeros((T, d), xf.dtype).at[token_of].add(
         ys * wflat[:, None].astype(xf.dtype))
 
 
-def _moe_ragged(cfg: ModelConfig, p: PyTree, xf: jnp.ndarray
-                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def _moe_ragged(cfg: ModelConfig, p: PyTree, xf: jnp.ndarray, w, ids
+                ) -> jnp.ndarray:
     T, d = xf.shape
-    w, ids, aux = _route(cfg, p, xf)                  # router on all tokens
     C = cfg.moe_chunk
     if not (C and T > C and T % C == 0):
-        return _dispatch_ragged(cfg, p, xf, w, ids), aux
+        return _dispatch_ragged(cfg, p, xf, w, ids)
 
     # token-chunked dispatch (§Perf): the (T*k, d)/(T*k, ff) dispatch
     # buffers never materialize for the full batch -- only per chunk.
@@ -115,14 +148,15 @@ def _moe_ragged(cfg: ModelConfig, p: PyTree, xf: jnp.ndarray
         return carry, _dispatch_ragged(cfg, p, xc, wc, ic)
 
     _, outs = jax.lax.scan(body, None, (xs, ws, idc))
-    return outs.reshape(T, d), aux
+    return outs.reshape(T, d)
 
 
-def _moe_dense(cfg: ModelConfig, p: PyTree, xf: jnp.ndarray
-               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    E = cfg.n_experts
-    w, ids, aux = _route(cfg, p, xf)
-    onehot = jax.nn.one_hot(ids, E, dtype=xf.dtype)   # (T, k, E)
+def _moe_dense(cfg: ModelConfig, p: PyTree, xf: jnp.ndarray, w, ids
+               ) -> jnp.ndarray:
+    E = cfg.held_experts
+    # absent experts' ids fall outside [0, E): their one-hot rows are 0
+    onehot = jax.nn.one_hot(ids - cfg.expert_offset, E,
+                            dtype=xf.dtype)           # (T, k, E)
     combine = jnp.einsum("tk,tke->te", w, onehot)     # (T, E)
 
     def expert(e):
@@ -130,8 +164,7 @@ def _moe_dense(cfg: ModelConfig, p: PyTree, xf: jnp.ndarray
         return h @ p["down"][e]
 
     ys = jax.vmap(expert)(jnp.arange(E))              # (E, T, d)
-    out = jnp.einsum("te,etd->td", combine, ys)
-    return out, aux
+    return jnp.einsum("te,etd->td", combine, ys)
 
 
 def _expert_axis_size() -> int:
@@ -145,8 +178,8 @@ def _expert_axis_size() -> int:
     return 0
 
 
-def _moe_expert_parallel(cfg: ModelConfig, p: PyTree, xf: jnp.ndarray
-                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def _moe_expert_parallel(cfg: ModelConfig, p: PyTree, xf: jnp.ndarray, w,
+                         ids) -> jnp.ndarray:
     """Expert-parallel MoE (beyond-paper §Perf): experts sharded over the
     'model' axis on the EXPERT dim, GShard-style capacity dispatch.
 
@@ -163,7 +196,6 @@ def _moe_expert_parallel(cfg: ModelConfig, p: PyTree, xf: jnp.ndarray
     T, d = xf.shape
     k, E = cfg.experts_per_token, cfg.n_experts
     m = _expert_axis_size()
-    w, ids, aux = _route(cfg, p, xf)
     cap = max(int(2.0 * T * k / E), 8)
 
     flat_ids = ids.reshape(-1)                          # (T*k,)
@@ -203,7 +235,7 @@ def _moe_expert_parallel(cfg: ModelConfig, p: PyTree, xf: jnp.ndarray
                   P(None, None), P(None), P(None), P(None), P(None)),
         out_specs=P(None, None), check_vma=False,
     )(p["gate"], p["up"], p["down"], xf, flat_ids, rank, keep, wflat)
-    return out, aux
+    return out
 
 
 def moe_apply(cfg: ModelConfig, p: PyTree, x: jnp.ndarray
@@ -211,13 +243,18 @@ def moe_apply(cfg: ModelConfig, p: PyTree, x: jnp.ndarray
     """x (B, S, d) -> (y (B, S, d), aux_loss scalar)."""
     B, S, d = x.shape
     xf = x.reshape(B * S, d)
-    if (cfg.moe_sharding == "expert" and _expert_axis_size() > 1
-            and cfg.n_experts % _expert_axis_size() == 0):
-        out, aux = _moe_expert_parallel(cfg, p, xf)
-    elif cfg.moe_impl == "ragged":
-        out, aux = _moe_ragged(cfg, p, xf)
-    else:
-        out, aux = _moe_dense(cfg, p, xf)
+    with jax.named_scope("moe_router"):
+        w, ids, aux = _route(cfg, p, xf)              # router on all tokens
+    with jax.named_scope("moe_experts"):
+        if (cfg.moe_sharding == "expert" and not cfg.experts_held
+                and _expert_axis_size() > 1
+                and cfg.n_experts % _expert_axis_size() == 0):
+            out = _moe_expert_parallel(cfg, p, xf, w, ids)
+        elif cfg.moe_impl == "ragged":
+            out = _moe_ragged(cfg, p, xf, w, ids)
+        else:
+            out = _moe_dense(cfg, p, xf, w, ids)
     if cfg.n_shared_experts:
-        out = out + mlp_apply(p["shared"], xf, "swiglu")
+        with jax.named_scope("moe_shared"):
+            out = out + mlp_apply(p["shared"], xf, "swiglu")
     return out.reshape(B, S, d), aux

@@ -152,7 +152,9 @@ def test_execute_at_a_new_K_compiles_nothing(backend, dropout, K):
         params, _ = engine.execute(plan, params, batches)
         jax.block_until_ready(params)
     # no trace, lowering or backend compile: the program cache alone
-    assert rec.counters == {HIT: [0.0, 1]}
+    # (and the engine's count of its cohort form)
+    assert rec.counters == {HIT: [0.0, 1],
+                            ("engine.cohort", "vmap"): [0.0, 1]}
 
 
 @pytest.mark.parametrize("backend,dropout", [
